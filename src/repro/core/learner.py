@@ -51,6 +51,7 @@ window k so each window's accumulated loss is a window mean.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, NamedTuple, Protocol
 
 import jax
@@ -144,6 +145,22 @@ class Learner(Protocol):
     def rewire(self, carry: Tree, event_key: jax.Array, *,
                frac: float = 0.1, method: str = "rigl",
                block: int = 1) -> Tree: ...
+
+
+def exact_matmuls(step):
+    """Trace an exact engine's `step` with every matmul at HIGHEST precision.
+
+    XLA:TPU runs an f32 dot at DEFAULT precision as one bf16 pass, which
+    breaks the exactness the engines below claim (and can flip an EGRU event
+    gate).  Under `jax.default_matmul_precision("highest")` the forward
+    partials, the influence contractions (XLA einsums and the Pallas kernels'
+    dots alike) and the gradient extraction all contract in f32.  XLA:CPU
+    computes f32 dots either way, so CPU results are unchanged."""
+    @functools.wraps(step)
+    def wrapped(self, carry, x_t, y_t):
+        with jax.default_matmul_precision("highest"):
+            return step(self, carry, x_t, y_t)
+    return wrapped
 
 
 class _LearnerBase:
@@ -330,6 +347,7 @@ class SparseLearner(_LearnerBase):
             return self._cl
         return dataclasses.replace(self._cl, **rw["cl"])
 
+    @exact_matmuls
     def step(self, carry, x_t, y_t):
         cfg, params = self.cfg, carry["params"]
         w = cells.rec_param_tree(params)
@@ -699,6 +717,7 @@ class StackedLearner(_LearnerBase):
             return a_new, hp, Jhat, None, mbar
         return self.lcells[l].partials_full(ws[l], a_prev, inp)
 
+    @exact_matmuls
     def step(self, carry, x_t, y_t):
         cfg, params = self.cfg, carry["params"]
         ws = params["layers"]
@@ -946,6 +965,7 @@ class ScaledLearner(_LearnerBase):
             return self._cl
         return dataclasses.replace(self._cl, **rw["cl"])
 
+    @exact_matmuls
     def step(self, carry, x_t, y_t):
         from repro.core import scaled_rtrl as SC
         from repro.kernels.compact import compact_grads
@@ -1126,6 +1146,7 @@ class DiagExactLearner(_LearnerBase):
         carry["gout"] = jax.tree.map(jnp.zeros_like, params["out"])
         return carry
 
+    @exact_matmuls
     def step(self, carry, x_t, y_t):
         params = carry["params"]
         w = self.cell.rec_params(params)

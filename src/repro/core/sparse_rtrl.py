@@ -711,7 +711,8 @@ def flat_compact_fused_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     so this backend rejects runtime-rewired ColLayouts).  use_kernel: None
     = auto (the Pallas grid on TPU, the blocked-switch XLA lowering
     elsewhere); True forces the Pallas kernel (interpret-mode off-TPU —
-    how the parity tests drive it)."""
+    how the parity tests drive it).  On TPU the kernel is the only path:
+    a capacity it cannot tile raises rather than falling back to XLA."""
     from repro.kernels import compact as CK
     from repro.kernels import compact_fused as CF
     n = layout.n
@@ -719,7 +720,7 @@ def flat_compact_fused_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     if segments is None:
         segments = CF.fused_segments(layout, cl, layer=layer)
     if use_kernel is None:
-        use_kernel = CF._on_tpu() and K % 8 == 0
+        use_kernel = CF._on_tpu()
     if below is None:
         a_new, hp, Jhat, mbar = cell_partials(cfg, w, a_prev, x_t)
         Bhat = None
@@ -733,40 +734,35 @@ def flat_compact_fused_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     count_prev = jnp.sum(idx_prev >= 0, axis=1)
     overflow = jnp.maximum(count - K, 0)
     count_new = jnp.minimum(count, K)
-    if use_kernel:
-        # TPU grid: in-kernel gather from the dense J-hat (rnn: R^T tiles
-        # broadcast — the kernel path trades that buffer for one HBM pass)
-        if cfg.kind == "rnn":
-            Jhat = jnp.broadcast_to(w["v"]["R"].T[None], (B, n, n))
-        mbar_rows = flat_mbar_rows_cols(cfg, layout, cl, mbar, safe_new,
-                                        layer=layer)
-        if below is not None:
-            vals_b, idx_b = below
-            AT = w["v"]["W"] if cfg.kind == "rnn" else None
-            Bgg = CK.gather_tiles(None if AT is not None else Bhat,
-                                  idx_new, idx_b, AT=AT)
-            mbar_rows = mbar_rows + jnp.einsum(
-                "bkj,bjp->bkp", Bgg, vals_b.astype(jnp.float32),
-                preferred_element_type=jnp.float32)
-        new_vals = CF.fused_update_pallas(
-            Jhat.astype(jnp.float32), vals, mbar_rows, hp_rows,
-            idx_new, idx_prev, count_new, count_prev, interpret=interpret)
-        return a_new, hp, new_vals, idx_new, count_new, overflow
-    # XLA lowering: per-example blocked dots over a static capacity ladder,
-    # M-bar generated inline at each gate's compact column segment
+    # rnn J-hat = R^T: lookup tiles straight from R, never building [B, n, n]
     R = w["v"]["R"] if cfg.kind == "rnn" else None
     Jgg = CK.gather_j_tiles(None if R is not None else Jhat,
                             idx_new, idx_prev, R=R)
-    below_t = None
+    Bgg = None
     if below is not None:
         vals_b, idx_b = below
         AT = w["v"]["W"] if cfg.kind == "rnn" else None
         Bgg = CK.gather_tiles(None if AT is not None else Bhat,
                               idx_new, idx_b, AT=AT)
-        below_t = (Bgg, vals_b)
+    if use_kernel:
+        # TPU grid over the XLA-gathered tiles; M-bar rows built at compact
+        # width, the cross-layer injection folded into them
+        mbar_rows = flat_mbar_rows_cols(cfg, layout, cl, mbar, safe_new,
+                                        layer=layer)
+        if Bgg is not None:
+            mbar_rows = mbar_rows + jnp.einsum(
+                "bkj,bjp->bkp", Bgg, vals_b.astype(jnp.float32),
+                preferred_element_type=jnp.float32)
+        new_vals = CF.fused_update_pallas(
+            Jgg, vals, mbar_rows, hp_rows, count_new, count_prev,
+            interpret=interpret)
+        return a_new, hp, new_vals, idx_new, count_new, overflow
+    # XLA lowering: per-example blocked dots over a static capacity ladder,
+    # M-bar generated inline at each gate's compact column segment
     new_vals = CF.fused_update_blocks(
         mbar, safe_new, hp_rows, Jgg, vals, count_new, count_prev,
-        segments, hp_full=hp, n=n, below=below_t)
+        segments, hp_full=hp, n=n,
+        below=None if Bgg is None else (Bgg, vals_b))
     return a_new, hp, new_vals, idx_new, count_new, overflow
 
 

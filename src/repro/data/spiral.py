@@ -31,6 +31,20 @@ def spiral_dataset(n_samples: int = 10_000, T: int = 17, noise: float = 0.05,
     return xs.astype(np.float32), labels
 
 
+def spiral_stream(batch_size: int, T: int = 17, seed: int = 0):
+    """The spiral task as an unbounded online stream: `stream(step) ->
+    (x_t [B, 2], labels [B])`, one freshly drawn batch of sequences every T
+    steps.  Step-keyed, so a restarted worker replays its exact inputs."""
+    xs_all, ys_all = spiral_dataset(T=T, seed=0)
+
+    def stream(step: int):
+        s, t = divmod(step, T)
+        rng = np.random.default_rng(1234 + seed * 100003 + s)
+        sel = rng.integers(0, ys_all.shape[0], size=batch_size)
+        return xs_all[sel][:, t], ys_all[sel]
+    return stream
+
+
 def spiral_batches(batch_size: int, T: int = 17, n_samples: int = 10_000,
                    seed: int = 0, time_major: bool = True):
     """Infinite batch iterator -> (xs [T,B,2] (or [B,T,2]), labels [B])."""

@@ -1,5 +1,5 @@
-"""Fused dual-compact influence update: gather + contract + Mbar + scale,
-one invocation per step, ragged per example.
+"""Fused dual-compact influence update: contract + Mbar + scale, one
+invocation per step, ragged per example.
 
 This is the accelerator-native form of the paper's combined
 omega~ beta~(t) beta~(t-1) n^2 p  influence-update cost (Table 1, "RTRL +
@@ -17,7 +17,7 @@ factors:
   in-kernel l-loop (prev-row blocks, bl)   beta~(t-1) n    active PREV rows
   grid axis 2 (column blocks of size bp)   omega~ p        live param columns
 
-Capacity is RAGGED PER EXAMPLE: the row-index arrays are scalar-prefetched,
+Capacity is RAGGED PER EXAMPLE: the live row counts are scalar-prefetched,
 and grid blocks past example b's live count are skipped with @pl.when (row
 blocks) / lax.cond (prev-row blocks), so executed compute is
 Sigma_b K_b K'_b Pc instead of B K_max^2 Pc — the batch tax dies without
@@ -25,11 +25,14 @@ changing the carry pytree shape ([B, K, Pc] + [B, K] indices, as before).
 
 Two lowerings of the SAME block structure:
 
-  * `fused_update_pallas` — the TPU kernel (pl.pallas_call): J tiles are
-    gathered in-kernel from the dense J-hat via the prefetched indices, the
-    (bk x bl) x (bl x bp) partial products accumulate in f32 on the MXU,
-    M-bar adds and the hp diagonal scale apply before the single output
-    write.  Validated on CPU with interpret=True (tests/test_compact_fused).
+  * `fused_update_pallas` — the TPU kernel (pl.pallas_call): the [B, K, K']
+    J tiles are gathered in XLA (`compact.gather_j_tiles`, 1/Pc of the
+    carry's bytes) and enter as a blocked input, so the kernel body holds
+    only static slices, which Mosaic lowers; the (bk x bl) x (bl x bp)
+    partial products accumulate in f32 on the MXU, M-bar adds and the hp
+    diagonal scale apply before the single output write.  Checked on CPU
+    with interpret=True (tests/test_compact_fused.py) and compiled for a
+    described v5e (tests/test_tpu_compile.py).
   * `fused_update_blocks` — the XLA lowering for hosts without a TPU grid:
     the same per-example blocking, with the data-dependent skip realised as
     a lax.switch over a static capacity ladder (smallest 8-aligned rung
@@ -56,8 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-
-from repro.kernels import compact as CK
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # gate-segment kinds on the compact column axis (see fused_segments)
 _DIAG, _RGATE, _THETA = "diag", "r", "theta"
@@ -236,15 +239,13 @@ def fused_update_blocks(mbar, safe_new, hp_rows, Jgg, vals, count_new,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel: in-kernel gather, ragged @pl.when grid skips
+# Pallas TPU kernel: ragged @pl.when grid skips over XLA-gathered J tiles
 # ---------------------------------------------------------------------------
 
-def _fused_kernel(idx_new_ref, idx_prev_ref, cnt_new_ref, cnt_prev_ref,
-                  J_ref, vals_ref, mbar_ref, hp_ref, out_ref, *,
-                  bk: int, bl: int, nlb: int):
+def _fused_kernel(cnt_new_ref, cnt_prev_ref, J_ref, vals_ref, mbar_ref,
+                  hp_ref, out_ref, *, bk: int, bl: int, nlb: int):
     b = pl.program_id(0)
-    kb = pl.program_id(1)
-    row_base = kb * bk
+    row_base = pl.program_id(1) * bk
 
     @pl.when(row_base >= cnt_new_ref[b])
     def _dead():                       # ragged per-example row-block skip
@@ -252,105 +253,87 @@ def _fused_kernel(idx_new_ref, idx_prev_ref, cnt_new_ref, cnt_prev_ref,
 
     @pl.when(row_base < cnt_new_ref[b])
     def _live():
-        n = J_ref.shape[-1]
-        # gather the bk J-hat rows once (active NEW units, prefetched idx)
-        jrows = []
-        for i in range(bk):
-            r = idx_new_ref[b, row_base + i]
-            jrows.append(J_ref[0, pl.ds(jnp.maximum(r, 0), 1), :])
-        Jg = jnp.concatenate(jrows, axis=0)              # [bk, n]
         acc = jnp.zeros(out_ref.shape[1:], jnp.float32)
         for lb in range(nlb):          # ragged prev-row blocks
             def contract(a, lb=lb):
-                cols = []
-                for jj in range(bl):
-                    c = idx_prev_ref[b, lb * bl + jj]
-                    col = lax.dynamic_slice(
-                        Jg, (0, jnp.maximum(c, 0)), (bk, 1))
-                    cols.append(jnp.where(c >= 0, col, 0.0))
-                Jt = jnp.concatenate(cols, axis=1)       # [bk, bl]
-                vblk = vals_ref[0, pl.ds(lb * bl, bl), :].astype(jnp.float32)
-                return a + lax.dot(Jt, vblk,
+                Jt = J_ref[0, :, lb * bl:(lb + 1) * bl]            # [bk, bl]
+                vblk = vals_ref[0, lb * bl:(lb + 1) * bl, :].astype(
+                    jnp.float32)                                   # [bl, bp]
+                return a + lax.dot(Jt, vblk, precision=lax.Precision.HIGHEST,
                                    preferred_element_type=jnp.float32)
             acc = lax.cond(lb * bl < cnt_prev_ref[b], contract,
                            lambda a: a, acc)
         acc = acc + mbar_ref[0].astype(jnp.float32)
-        hpv = hp_ref[0]
-        out_ref[0] = (hpv[:, None] * acc).astype(out_ref.dtype)
-
-
-try:                                   # gate: environments without Pallas
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-    _CompilerParams = (getattr(pltpu, "CompilerParams", None)
-                       or getattr(pltpu, "TPUCompilerParams"))
-except Exception:                      # pragma: no cover
-    pl = pltpu = _CompilerParams = None
-    _HAS_PALLAS = False
+        out_ref[0] = (hp_ref[0] * acc).astype(out_ref.dtype)       # hp [bk, 1]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("bk", "bl", "bp", "interpret"))
-def fused_update_pallas(Jhat, vals, mbar_rows, hp_rows, idx_new, idx_prev,
-                        count_new, count_prev, *, bk: int = 8, bl: int = 8,
-                        bp: int = 128, interpret: bool | None = None):
+def fused_update_pallas(Jgg, vals, mbar_rows, hp_rows, count_new, count_prev,
+                        *, bk: int = 8, bl: int = 8, bp: int = 128,
+                        interpret: bool | None = None):
     """One fused dual-compact influence update on the TPU grid.
 
-    Jhat [B, n, n] f32 dense step Jacobian; vals [B, K, Pc_pad] compact
-    carry (f32 or bf16); mbar_rows [B, K, Pc_pad] M-bar gathered at the new
-    active rows (hp-ungated); hp_rows [B, K] with dead slots zeroed;
-    idx_new/idx_prev [B, K] (-1 sentinel, scalar-prefetched);
-    count_new/count_prev [B].  Returns the new carry in vals.dtype.
+    Jgg [B, K, K] f32 J-hat tiles gathered at (new rows, prev rows) by
+    `compact.gather_j_tiles` (dead prev columns zeroed); vals [B, K, Pc_pad]
+    compact carry (f32 or bf16); mbar_rows [B, K, Pc_pad] M-bar gathered at
+    the new active rows (hp-ungated); hp_rows [B, K] with dead slots
+    zeroed; count_new/count_prev [B] (scalar-prefetched).  Returns the new
+    carry in vals.dtype.
 
     Grid (B, K/bk, Pc_pad/bp); row blocks beyond count_new[b] and prev-row
     blocks beyond count_prev[b] are skipped per example, so executed MXU
     work is Sigma_b K_b K'_b Pc — see the module docstring for the mapping
-    onto the paper's cost terms."""
-    if not _HAS_PALLAS:                # pragma: no cover
-        raise RuntimeError("Pallas unavailable; use fused_update_blocks")
+    onto the paper's cost terms.  The block dots run at HIGHEST precision
+    (f32 contraction on the MXU): Mosaic would otherwise be free to pick a
+    bf16 pass, and the engine claims exact gradients.  interpret: None =
+    compiled Mosaic on a TPU, the Pallas interpreter elsewhere."""
     B, K, Pc_pad = vals.shape
-    n = Jhat.shape[-1]
-    assert K % bk == 0 and K % bl == 0 and Pc_pad % bp == 0, \
-        (K, bk, bl, Pc_pad, bp)
-    nlb = K // bl
+    if Jgg.shape != (B, K, K):
+        raise ValueError(f"Jgg {Jgg.shape} must be the [B, K, K] = "
+                         f"{(B, K, K)} tiles of the carry")
+    if K % bk or K % bl or Pc_pad % bp:
+        raise ValueError(f"fused kernel tiling needs K % {bk} == K % {bl} "
+                         f"== 0 and Pc_pad % {bp} == 0; got K={K}, "
+                         f"Pc_pad={Pc_pad}")
     interpret = (not _on_tpu()) if interpret is None else interpret
-    grid = (B, K // bk, Pc_pad // bp)
-    kernel = functools.partial(_fused_kernel, bk=bk, bl=bl, nlb=nlb)
+    kernel = functools.partial(_fused_kernel, bk=bk, bl=bl, nlb=K // bl)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=grid,
+            num_scalar_prefetch=2,
+            grid=(B, K // bk, Pc_pad // bp),
             in_specs=[
-                pl.BlockSpec((1, n, n), lambda b, kb, pb, *_: (b, 0, 0)),
+                pl.BlockSpec((1, bk, K), lambda b, kb, pb, *_: (b, kb, 0)),
                 pl.BlockSpec((1, K, bp), lambda b, kb, pb, *_: (b, 0, pb)),
                 pl.BlockSpec((1, bk, bp), lambda b, kb, pb, *_: (b, kb, pb)),
-                pl.BlockSpec((1, bk), lambda b, kb, pb, *_: (b, kb)),
+                # hp as a [B, K, 1] column: (bk, 1) obeys the (8, 128)
+                # block rule (1 is the full last dim), a (1, bk) row does not
+                pl.BlockSpec((1, bk, 1), lambda b, kb, pb, *_: (b, kb, 0)),
             ],
             out_specs=pl.BlockSpec((1, bk, bp),
                                    lambda b, kb, pb, *_: (b, kb, pb)),
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, Pc_pad), vals.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(idx_new, idx_prev, count_new, count_prev,
-      Jhat, vals, mbar_rows, hp_rows)
+    )(count_new, count_prev, Jgg.astype(jnp.float32), vals, mbar_rows,
+      hp_rows[:, :, None].astype(jnp.float32))
 
 
-def fused_reference(Jhat, vals, mbar_rows, hp_rows, idx_new, idx_prev,
-                    count_new, count_prev, *, bl: int = 8):
+def fused_reference(Jgg, vals, mbar_rows, hp_rows, count_new, count_prev,
+                    *, bl: int = 8):
     """Pure-jnp oracle with the KERNEL's blockwise accumulation order
     (l blocks of bl, ascending), so f32 parity with interpret-mode
     `fused_update_pallas` is bitwise: summing a dead block's exact zeros
     is the identity, and live blocks add in the same order."""
     B, K, Pc_pad = vals.shape
-    Jgg = CK.gather_j_tiles(Jhat, idx_new, idx_prev)
     acc = jnp.zeros((B, K, Pc_pad), jnp.float32)
     for lb in range(K // bl):
         blk = jnp.einsum("bkl,blp->bkp", Jgg[:, :, lb * bl:(lb + 1) * bl],
                          vals[:, lb * bl:(lb + 1) * bl].astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
         live = (lb * bl < count_prev).astype(jnp.float32)[:, None, None]
         acc = acc + blk * live

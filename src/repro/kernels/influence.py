@@ -54,10 +54,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across JAX versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _kernel(row_mask_ref, prev_mask_ref, col_mask_ref, jmask_ref,
             hp_ref, J_ref, M_ref, Mbar_ref, out_ref, *, bl: int, nlb: int):
@@ -81,12 +77,13 @@ def _kernel(row_mask_ref, prev_mask_ref, col_mask_ref, jmask_ref,
                 j_blk = J_ref[0, :, _lb * bl:(_lb + 1) * bl]      # [bk, bl]
                 m_blk = M_ref[0, _lb * bl:(_lb + 1) * bl, :]      # [bl, bp]
                 return acc + jax.lax.dot(
-                    j_blk, m_blk, preferred_element_type=jnp.float32)
+                    j_blk, m_blk, precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
 
             acc = jax.lax.cond(pred, compute, lambda a: a, acc)
         acc = acc + Mbar_ref[0]
-        hpv = hp_ref[0]                                   # [bk]
-        out_ref[0] = (hpv[:, None] * acc).astype(out_ref.dtype)
+        hpv = hp_ref[0]                                   # [bk, 1]
+        out_ref[0] = (hpv * acc).astype(out_ref.dtype)
 
 
 def influence_update_pallas(hp, Jhat, M, Mbar, *, row_mask, prev_mask,
@@ -110,7 +107,9 @@ def influence_update_pallas(hp, Jhat, M, Mbar, *, row_mask, prev_mask,
             num_scalar_prefetch=4,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bk), lambda b, kb, pb, *_: (b, kb)),        # hp
+                # hp as a [B, n, 1] column: (bk, 1) obeys the (8, 128)
+                # block rule, a (1, bk) row block does not
+                pl.BlockSpec((1, bk, 1), lambda b, kb, pb, *_: (b, kb, 0)),  # hp
                 pl.BlockSpec((1, bk, n), lambda b, kb, pb, *_: (b, kb, 0)),  # Jhat
                 pl.BlockSpec((1, n, bp), lambda b, kb, pb, *_: (b, 0, pb)),  # M
                 pl.BlockSpec((1, bk, bp), lambda b, kb, pb, *_: (b, kb, pb)),# Mbar
@@ -118,10 +117,10 @@ def influence_update_pallas(hp, Jhat, M, Mbar, *, row_mask, prev_mask,
             out_specs=pl.BlockSpec((1, bk, bp), lambda b, kb, pb, *_: (b, kb, pb)),
         ),
         out_shape=jax.ShapeDtypeStruct((B, n, P), M.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(row_mask, prev_mask, col_mask, jmask, hp, Jhat, M, Mbar)
+    )(row_mask, prev_mask, col_mask, jmask, hp[:, :, None], Jhat, M, Mbar)
     return out
 
 

@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across JAX versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _kernel(r_ref, k_ref, v_ref, logw_ref, u_ref, o_ref, s_ref, *, L: int):
     c = pl.program_id(1)
@@ -98,7 +94,7 @@ def wkv_pallas(r, k, v, logw, u, *, chunk: int = 16, interpret: bool = None):
         out_specs=pl.BlockSpec((1, L, D), lambda bh, c: (bh, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r2, k2, v2, w2, u2)

@@ -19,22 +19,30 @@ import time
 import numpy as np
 
 
-def _fleet_main(args):
-    """Drain a queue of online-RTRL sessions through one StreamFleet."""
+def session_stream(seed: int, B: int, n_in: int, n_out: int):
+    """One session's step-keyed stream: Gaussian inputs [B, n_in] and fixed
+    labels [B] — replay-exact, so an evicted session resumes bit-for-bit."""
+    def stream(step: int):
+        rng = np.random.default_rng(seed * 100003 + step)
+        x = rng.standard_normal((B, n_in)).astype(np.float32)
+        y = (np.arange(B, dtype=np.int32) + seed) % n_out
+        return x, y
+    return stream
+
+
+def build_fleet(n: int, B: int, slots: int, update_every: int,
+                telemetry=None):
+    """The --fleet deployment: EGRU width n at 90% parameter sparsity on
+    the dual-compact engine, one StreamFleet of `slots` sessions, each
+    streaming batches of B.  Returns (fleet, params0), params0 being the
+    parameters every joining session starts from."""
     import jax
 
     from repro.core import cells, sparse_rtrl as SP
     from repro.core.cells import EGRUConfig
     from repro.core.learner import LearnerSpec, make_learner
-    from repro.obs import finish_run, telemetry_from_args
     from repro.optim import make_optimizer
     from repro.runtime.fleet import FleetConfig, StreamFleet
-
-    n = 16 if args.smoke else 96
-    B = 2 if args.smoke else 8
-    n_sessions = min(args.requests, 6) if args.smoke else args.requests
-    slots = min(args.slots, 4) if args.smoke else args.slots
-    windows = 3 if args.smoke else args.session_windows
 
     cfg = EGRUConfig(n_hidden=n, n_in=3, n_out=2, kind="gru")
     masks = SP.make_masks(cfg, jax.random.key(7), 0.9)
@@ -42,21 +50,28 @@ def _fleet_main(args):
                                        backend="compact", col_compact=True))
     opt = make_optimizer("adamw", lr=1e-3)
     params0 = SP.apply_masks(cells.init_params(cfg, jax.random.key(0)), masks)
+    fleet = StreamFleet(FleetConfig(slots=slots, update_every=update_every),
+                        learner, opt, params0, masks,
+                        example=session_stream(0, B, cfg.n_in, cfg.n_out)(0),
+                        telemetry=telemetry)
+    return fleet, params0
 
-    def make_stream(seed: int):
-        def stream(step: int):
-            rng = np.random.default_rng(seed * 100003 + step)
-            x = rng.standard_normal((B, cfg.n_in)).astype(np.float32)
-            y = (np.arange(B, dtype=np.int32) + seed) % cfg.n_out
-            return x, y
-        return stream
+
+def _fleet_main(args):
+    """Drain a queue of online-RTRL sessions through one StreamFleet."""
+    from repro.obs import finish_run, telemetry_from_args
+
+    n = 16 if args.smoke else 96
+    B = 2 if args.smoke else 8
+    n_sessions = min(args.requests, 6) if args.smoke else args.requests
+    slots = min(args.slots, 4) if args.smoke else args.slots
+    windows = 3 if args.smoke else args.session_windows
 
     obs = telemetry_from_args(args, mode="fleet", slots=slots,
                               sessions=n_sessions)
-    fleet = StreamFleet(FleetConfig(slots=slots,
-                                    update_every=args.update_every),
-                        learner, opt, params0, masks,
-                        example=make_stream(0)(0), telemetry=obs)
+    fleet, _ = build_fleet(n, B, slots, args.update_every, telemetry=obs)
+    cfg = fleet.learner.cfg
+    make_stream = lambda i: session_stream(i, B, cfg.n_in, cfg.n_out)
     queue = [(f"s{i}", make_stream(i)) for i in range(n_sessions)]
     need = {sid: windows for sid, _ in queue}
     done, fleet_windows = 0, 0
@@ -105,6 +120,8 @@ def main():
     from repro.obs import add_obs_args
     add_obs_args(ap)
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.fleet:
         return _fleet_main(args)

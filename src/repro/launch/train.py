@@ -48,6 +48,7 @@ from repro.configs import SHAPES, get_config, smoke_config
 from repro.data.pipeline import ShardedHostLoader
 from repro.data.tokens import synthetic_token_batches
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import setup_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import get_model
 from repro.models.module import materialize, tree_shardings
@@ -190,7 +191,7 @@ def train_egru_online(args, cfg, masks, opt, backend, col_compact) -> dict:
     so restarts resume mid-stream.  `--steps` counts optimizer updates."""
     from repro.core import cells, stacked_rtrl as ST
     from repro.core.learner import LearnerSpec, make_learner
-    from repro.data.spiral import spiral_dataset
+    from repro.data.spiral import spiral_stream
     from repro.runtime.online import OnlineTrainer, OnlineTrainerConfig
     from repro.sparsity import RewireSchedule
 
@@ -215,16 +216,9 @@ def train_egru_online(args, cfg, masks, opt, backend, col_compact) -> dict:
                                   every_k=args.rewire_every,
                                   frac=args.rewire_frac, t_end=n_events)
 
-    T = cfg.seq_len
-    xs_all, ys_all = spiral_dataset(T=T, seed=0)
+    stream = spiral_stream(cfg.batch_size, T=cfg.seq_len, seed=args.seed)
     obs = telemetry_from_args(args, arch="egru-spiral", mode="online",
                               backend=backend, col_compact=col_compact)
-
-    def stream(step):    # step-keyed: replay-exact across restarts; one
-        s, t = divmod(step, T)                # spiral sequence per T steps
-        rng = np.random.default_rng(1234 + args.seed * 100003 + s)
-        sel = rng.integers(0, ys_all.shape[0], size=cfg.batch_size)
-        return xs_all[sel][:, t], ys_all[sel]
 
     def make_trainer(attempt=0):
         params = cells.init_stacked_params(cfg, jax.random.key(args.seed))
@@ -456,6 +450,7 @@ def main():
                          "one value reproduces a run end-to-end")
     add_obs_args(ap)
     args = ap.parse_args()
+    setup_compile_cache()
 
     if args.arch in ("egru-spiral", "egru_spiral"):
         train_egru(args)

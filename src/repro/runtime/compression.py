@@ -21,14 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-# shard_map moved out of jax.experimental (and check_rep became check_vma)
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _NOCHECK = {"check_vma": False}
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NOCHECK = {"check_rep": False}
-
 Tree = Any
 
 
@@ -69,9 +61,9 @@ def compressed_psum(grads: Tree, err: Tree, mesh: Mesh,
     # full-manual over the mesh; P() = replicated view per device.  Used in
     # the pure-DP-across-pods mode where grads are already reduced in-pod.
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(specs, specs), out_specs=(specs, specs),
-        **_NOCHECK)
+        check_vma=False)
     def go(gs, es):
         outs = [ef_int8_allreduce(g, e, axis_name) for g, e in zip(gs, es)]
         return tuple(o[0] for o in outs), tuple(o[1] for o in outs)

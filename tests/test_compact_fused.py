@@ -22,6 +22,7 @@ from repro.core import cells, scaled_rtrl as SC, sparse_rtrl as SP, \
     stacked_rtrl as ST
 from repro.core.cells import EGRUConfig
 from repro.core.learner import LearnerSpec, make_learner
+from repro.kernels import compact as CK
 from repro.kernels import compact_fused as CF
 
 
@@ -52,8 +53,9 @@ def _ragged_inputs(seed, B=3, K=16, n=40, Pc_pad=128, dtype=jnp.float32):
     hp = np.abs(rng.normal(size=(B, K))).astype(np.float32)
     hp[idx_new < 0] = 0.0
     to = lambda a: jnp.asarray(a)
-    return (to(Jhat), to(vals).astype(dtype), to(mbar), to(hp),
-            to(idx_new), to(idx_prev), to(count_new), to(count_prev))
+    Jgg = CK.gather_j_tiles(to(Jhat), to(idx_new), to(idx_prev))
+    return (Jgg, to(vals).astype(dtype), to(mbar), to(hp),
+            to(count_new), to(count_prev))
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -79,7 +81,7 @@ def test_interpret_kernel_bf16_bounded():
 def test_kernel_dead_rows_exact_zero():
     args = _ragged_inputs(4)
     out = np.asarray(CF.fused_update_pallas(*args, interpret=True))
-    count_new = np.asarray(args[6])
+    count_new = np.asarray(args[4])
     for b in range(out.shape[0]):
         assert (out[b, count_new[b]:] == 0.0).all()
 
@@ -137,8 +139,8 @@ def test_fused_matches_compact_and_dense(kind, sparsity):
 
 @pytest.mark.parametrize("kind", ["rnn", "gru"])
 def test_fused_pallas_interpret_path(kind):
-    """interpret=True drives the in-kernel gather / @pl.when grid through
-    the engine; must agree with the XLA lowering of the same step."""
+    """interpret=True drives the tile-fed @pl.when grid through the
+    engine; must agree with the XLA lowering of the same step."""
     cfg, params, masks, xs, labels = _setup(kind, 0.6, seed=2)
     l_x, g_x, _ = SP.sparse_rtrl_loss_and_grads(
         cfg, params, xs, labels, masks, backend="compact_fused")
