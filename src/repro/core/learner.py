@@ -358,35 +358,46 @@ class SparseLearner(_LearnerBase):
         new = dict(carry)
         extra_stats = {}
         if self.backend == "dense":
-            a_new, hp, Jhat, mbar = self.cell.partials(w, carry["a"], x_t)
-            M_new = SP.influence_update(cfg, carry["M"], hp, Jhat, mbar,
-                                        masks)
-            lt, (gout_t, cbar) = jax.value_and_grad(
-                self._inst_loss, argnums=(0, 1))(params["out"], a_new, y_t, tt)
-            gw_t = SP.influence_grads(cfg, M_new, cbar)
-            new["gw"] = jax.tree.map(jnp.add, carry["gw"], gw_t)
+            with jax.named_scope("partials"):
+                a_new, hp, Jhat, mbar = self.cell.partials(w, carry["a"], x_t)
+            with jax.named_scope("influence_update"):
+                M_new = SP.influence_update(cfg, carry["M"], hp, Jhat, mbar,
+                                            masks)
+            with jax.named_scope("grad_readout"):
+                lt, (gout_t, cbar) = jax.value_and_grad(
+                    self._inst_loss, argnums=(0, 1))(params["out"], a_new,
+                                                     y_t, tt)
+                gw_t = SP.influence_grads(cfg, M_new, cbar)
+                new["gw"] = jax.tree.map(jnp.add, carry["gw"], gw_t)
             new["M"] = M_new
-            row_density = SP._row_density(M_new)
+            with jax.named_scope("telemetry"):
+                row_density = SP._row_density(M_new)
         elif self.backend == "pallas":
             from repro.kernels import ops as kops
             colm = rw.get("colm", self._colm) if rw is not None else self._colm
             jm = rw["jmask"] if rw is not None else self._jm
-            a_new, hp, Jhat, mbar = self.cell.partials(w, carry["a"], x_t)
-            if cl is not None:
-                Mbar = SP.flat_mbar_cols(cfg, self.layout, cl, mbar)
-                kcolm = cl.live
-            else:
-                Mbar = SP.flat_mbar(cfg, self.layout, mbar, colm)
-                kcolm = colm
-            M_new = kops.influence_update(hp, Jhat, carry["M"], Mbar,
-                                          jmask=jm, col_mask=kcolm,
-                                          interpret=self.spec.interpret)
-            lt, (gout_t, cbar) = jax.value_and_grad(
-                self._inst_loss, argnums=(0, 1))(params["out"], a_new, y_t, tt)
-            gw_t = jnp.einsum("bk,bkp->p", cbar, M_new)
-            new["gw"] = carry["gw"] + gw_t
+            with jax.named_scope("partials"):
+                a_new, hp, Jhat, mbar = self.cell.partials(w, carry["a"], x_t)
+            with jax.named_scope("mbar_rows"):
+                if cl is not None:
+                    Mbar = SP.flat_mbar_cols(cfg, self.layout, cl, mbar)
+                    kcolm = cl.live
+                else:
+                    Mbar = SP.flat_mbar(cfg, self.layout, mbar, colm)
+                    kcolm = colm
+            with jax.named_scope("influence_update"):
+                M_new = kops.influence_update(hp, Jhat, carry["M"], Mbar,
+                                              jmask=jm, col_mask=kcolm,
+                                              interpret=self.spec.interpret)
+            with jax.named_scope("grad_readout"):
+                lt, (gout_t, cbar) = jax.value_and_grad(
+                    self._inst_loss, argnums=(0, 1))(params["out"], a_new,
+                                                     y_t, tt)
+                gw_t = jnp.einsum("bk,bkp->p", cbar, M_new)
+                new["gw"] = carry["gw"] + gw_t
             new["M"] = M_new
-            row_density = jnp.mean(jnp.any(M_new != 0.0, axis=2))
+            with jax.named_scope("telemetry"):
+                row_density = jnp.mean(jnp.any(M_new != 0.0, axis=2))
         else:                                   # compact / compact_fused
             from repro.kernels import compact as CK
             colm = rw.get("colm", self._colm) if rw is not None else self._colm
@@ -402,29 +413,36 @@ class SparseLearner(_LearnerBase):
                     SP.flat_compact_step(cfg, w, self.layout, carry["a"],
                                          carry["vals"], carry["idx"], x_t,
                                          colm, cl=cl)
-            lt, (gout_t, cbar) = jax.value_and_grad(
-                self._inst_loss, argnums=(0, 1))(params["out"], a_new, y_t, tt)
-            gw_t = CK.compact_grads(vals_new, idx_new, cbar)
-            new["gw"] = carry["gw"] + gw_t
+            with jax.named_scope("grad_readout"):
+                lt, (gout_t, cbar) = jax.value_and_grad(
+                    self._inst_loss, argnums=(0, 1))(params["out"], a_new,
+                                                     y_t, tt)
+                gw_t = CK.compact_grads(vals_new, idx_new, cbar)
+                new["gw"] = carry["gw"] + gw_t
             new["vals"], new["idx"] = vals_new, idx_new
-            row_density = (jnp.sum(idx_new >= 0, axis=1).mean()
-                           / cfg.n_hidden)
-            extra_stats["overflow"] = jnp.max(overflow)
+            with jax.named_scope("telemetry"):
+                row_density = (jnp.sum(idx_new >= 0, axis=1).mean()
+                               / cfg.n_hidden)
+                extra_stats["overflow"] = jnp.max(overflow)
         new["a"] = a_new
-        new["gout"] = jax.tree.map(jnp.add, carry["gout"], gout_t)
-        new["loss"] = carry["loss"] + lt
+        with jax.named_scope("grad_readout"):
+            new["gout"] = jax.tree.map(jnp.add, carry["gout"], gout_t)
+            new["loss"] = carry["loss"] + lt
+            logits = cells.readout(params, a_new)
         if rw is not None:
             new["last"] = {"x": x_t.astype(jnp.float32),
                            "y": y_t.astype(jnp.int32)}
-        stats = {"alpha": jnp.mean(a_new == 0.0), "beta": jnp.mean(hp == 0.0),
-                 "beta_prev": carry["beta_prev"],
-                 "m_row_density": row_density, **extra_stats}
+        with jax.named_scope("telemetry"):
+            stats = {"alpha": jnp.mean(a_new == 0.0),
+                     "beta": jnp.mean(hp == 0.0),
+                     "beta_prev": carry["beta_prev"],
+                     "m_row_density": row_density, **extra_stats}
         new["beta_prev"] = stats["beta"]
         step_grads = None
         if self.spec.per_step_grads:
             step_grads = self._finish_gw(gw_t, cl)
             step_grads["out"] = gout_t
-        out = StepOut(lt, cells.readout(params, a_new), stats, step_grads)
+        out = StepOut(lt, logits, stats, step_grads)
         return new, out
 
     def _finish_gw(self, gw, cl=None):

@@ -654,37 +654,45 @@ def flat_compact_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     from repro.kernels import compact as CK
     n = layout.n
     B, K = idx_prev.shape
-    if below is None:
-        a_new, hp, Jhat, mbar = cell_partials(cfg, w, a_prev, x_t)
-        Bhat = None
-    else:
-        a_new, hp, Jhat, Bhat, mbar = cell_partials_full(cfg, w, a_prev, x_t)
-    idx_new, count = CK.compact_rows(hp != 0.0, K)
-    safe_new = jnp.clip(idx_new, 0, n - 1)
-    live_new = idx_new >= 0
-    # rnn J-hat = R^T: lookup tiles straight from R, never building [B, n, n]
-    R = w["v"]["R"] if cfg.kind == "rnn" else None
-    Jgg = CK.gather_j_tiles(None if R is not None else Jhat,
-                            idx_new, idx_prev, R=R)
-    if cl is not None:
-        mbar_rows = flat_mbar_rows_cols(cfg, layout, cl, mbar, safe_new,
-                                        layer=layer)
-    else:
-        mbar_rows = flat_mbar_rows(cfg, layout, mbar, safe_new, col_mask,
-                                   offset=offset, total_pad=total_pad)
-    if below is not None:
-        vals_b, idx_b = below
-        if cfg.kind == "rnn":
-            # B-hat = W^T exactly: look tiles up from W
-            Bgg = CK.gather_tiles(None, idx_new, idx_b, AT=w["v"]["W"])
+    with jax.named_scope("partials"):
+        if below is None:
+            a_new, hp, Jhat, mbar = cell_partials(cfg, w, a_prev, x_t)
+            Bhat = None
         else:
-            Bgg = CK.gather_tiles(Bhat, idx_new, idx_b)
-        mbar_rows = mbar_rows + jnp.einsum("bkj,bjp->bkp", Bgg, vals_b,
-                                           preferred_element_type=jnp.float32)
-    bidx = jnp.arange(B)[:, None]
-    hp_rows = hp[bidx, safe_new] * live_new
-    Mc, overflow = CK.compact_update(Jgg, vals, mbar_rows, hp_rows,
-                                     idx_new, count, K)
+            a_new, hp, Jhat, Bhat, mbar = cell_partials_full(cfg, w, a_prev,
+                                                             x_t)
+    with jax.named_scope("j_tile_gather"):
+        idx_new, count = CK.compact_rows(hp != 0.0, K)
+        safe_new = jnp.clip(idx_new, 0, n - 1)
+        live_new = idx_new >= 0
+        # rnn J-hat = R^T: lookup tiles straight from R, never building
+        # [B, n, n]
+        R = w["v"]["R"] if cfg.kind == "rnn" else None
+        Jgg = CK.gather_j_tiles(None if R is not None else Jhat,
+                                idx_new, idx_prev, R=R)
+        bidx = jnp.arange(B)[:, None]
+        hp_rows = hp[bidx, safe_new] * live_new
+        if below is not None:
+            vals_b, idx_b = below
+            if cfg.kind == "rnn":
+                # B-hat = W^T exactly: look tiles up from W
+                Bgg = CK.gather_tiles(None, idx_new, idx_b, AT=w["v"]["W"])
+            else:
+                Bgg = CK.gather_tiles(Bhat, idx_new, idx_b)
+    with jax.named_scope("mbar_rows"):
+        if cl is not None:
+            mbar_rows = flat_mbar_rows_cols(cfg, layout, cl, mbar, safe_new,
+                                            layer=layer)
+        else:
+            mbar_rows = flat_mbar_rows(cfg, layout, mbar, safe_new, col_mask,
+                                       offset=offset, total_pad=total_pad)
+        if below is not None:
+            mbar_rows = mbar_rows + jnp.einsum(
+                "bkj,bjp->bkp", Bgg, vals_b,
+                preferred_element_type=jnp.float32)
+    with jax.named_scope("influence_update"):
+        Mc, overflow = CK.compact_update(Jgg, vals, mbar_rows, hp_rows,
+                                         idx_new, count, K)
     return a_new, hp, Mc.vals, Mc.idx, Mc.count, overflow
 
 
@@ -721,48 +729,55 @@ def flat_compact_fused_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
         segments = CF.fused_segments(layout, cl, layer=layer)
     if use_kernel is None:
         use_kernel = CF._on_tpu()
-    if below is None:
-        a_new, hp, Jhat, mbar = cell_partials(cfg, w, a_prev, x_t)
-        Bhat = None
-    else:
-        a_new, hp, Jhat, Bhat, mbar = cell_partials_full(cfg, w, a_prev, x_t)
-    idx_new, count = CK.compact_rows(hp != 0.0, K)
-    safe_new = jnp.clip(idx_new, 0, n - 1)
-    live_new = idx_new >= 0
-    bidx = jnp.arange(B)[:, None]
-    hp_rows = hp[bidx, safe_new] * live_new
-    count_prev = jnp.sum(idx_prev >= 0, axis=1)
-    overflow = jnp.maximum(count - K, 0)
-    count_new = jnp.minimum(count, K)
-    # rnn J-hat = R^T: lookup tiles straight from R, never building [B, n, n]
-    R = w["v"]["R"] if cfg.kind == "rnn" else None
-    Jgg = CK.gather_j_tiles(None if R is not None else Jhat,
-                            idx_new, idx_prev, R=R)
-    Bgg = None
-    if below is not None:
-        vals_b, idx_b = below
-        AT = w["v"]["W"] if cfg.kind == "rnn" else None
-        Bgg = CK.gather_tiles(None if AT is not None else Bhat,
-                              idx_new, idx_b, AT=AT)
+    with jax.named_scope("partials"):
+        if below is None:
+            a_new, hp, Jhat, mbar = cell_partials(cfg, w, a_prev, x_t)
+            Bhat = None
+        else:
+            a_new, hp, Jhat, Bhat, mbar = cell_partials_full(cfg, w, a_prev,
+                                                             x_t)
+    with jax.named_scope("j_tile_gather"):
+        idx_new, count = CK.compact_rows(hp != 0.0, K)
+        safe_new = jnp.clip(idx_new, 0, n - 1)
+        live_new = idx_new >= 0
+        bidx = jnp.arange(B)[:, None]
+        hp_rows = hp[bidx, safe_new] * live_new
+        count_prev = jnp.sum(idx_prev >= 0, axis=1)
+        overflow = jnp.maximum(count - K, 0)
+        count_new = jnp.minimum(count, K)
+        # rnn J-hat = R^T: lookup tiles straight from R, never building
+        # [B, n, n]
+        R = w["v"]["R"] if cfg.kind == "rnn" else None
+        Jgg = CK.gather_j_tiles(None if R is not None else Jhat,
+                                idx_new, idx_prev, R=R)
+        Bgg = None
+        if below is not None:
+            vals_b, idx_b = below
+            AT = w["v"]["W"] if cfg.kind == "rnn" else None
+            Bgg = CK.gather_tiles(None if AT is not None else Bhat,
+                                  idx_new, idx_b, AT=AT)
     if use_kernel:
         # TPU grid over the XLA-gathered tiles; M-bar rows built at compact
         # width, the cross-layer injection folded into them
-        mbar_rows = flat_mbar_rows_cols(cfg, layout, cl, mbar, safe_new,
-                                        layer=layer)
-        if Bgg is not None:
-            mbar_rows = mbar_rows + jnp.einsum(
-                "bkj,bjp->bkp", Bgg, vals_b.astype(jnp.float32),
-                preferred_element_type=jnp.float32)
-        new_vals = CF.fused_update_pallas(
-            Jgg, vals, mbar_rows, hp_rows, count_new, count_prev,
-            interpret=interpret)
+        with jax.named_scope("mbar_rows"):
+            mbar_rows = flat_mbar_rows_cols(cfg, layout, cl, mbar, safe_new,
+                                            layer=layer)
+            if Bgg is not None:
+                mbar_rows = mbar_rows + jnp.einsum(
+                    "bkj,bjp->bkp", Bgg, vals_b.astype(jnp.float32),
+                    preferred_element_type=jnp.float32)
+        with jax.named_scope("influence_update"):
+            new_vals = CF.fused_update_pallas(
+                Jgg, vals, mbar_rows, hp_rows, count_new, count_prev,
+                interpret=interpret)
         return a_new, hp, new_vals, idx_new, count_new, overflow
     # XLA lowering: per-example blocked dots over a static capacity ladder,
     # M-bar generated inline at each gate's compact column segment
-    new_vals = CF.fused_update_blocks(
-        mbar, safe_new, hp_rows, Jgg, vals, count_new, count_prev,
-        segments, hp_full=hp, n=n,
-        below=None if Bgg is None else (Bgg, vals_b))
+    with jax.named_scope("influence_update"):
+        new_vals = CF.fused_update_blocks(
+            mbar, safe_new, hp_rows, Jgg, vals, count_new, count_prev,
+            segments, hp_full=hp, n=n,
+            below=None if Bgg is None else (Bgg, vals_b))
     return a_new, hp, new_vals, idx_new, count_new, overflow
 
 

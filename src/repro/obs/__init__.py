@@ -8,9 +8,9 @@ Three layers (see ROADMAP "telemetry plane"):
 - `Registry` / `EventLog` — host-side counters, gauges, fixed-bucket
   histograms (interpolated p50/p95/p99), schema-versioned JSONL events,
   Prometheus text exposition (`repro.obs.registry`, `repro.obs.events`).
-- `Tracer` — nested wall-clock spans with Chrome-trace export and
-  optional `jax.profiler.TraceAnnotation` passthrough
-  (`repro.obs.trace`).
+- `Tracer` — nested host spans (parent, self time, profiler clock) as
+  `jax.profiler.TraceAnnotation`s, and the map from a compiled program's
+  instructions to its named stages (`repro.obs.trace`).
 
 `Telemetry` (`repro.obs.telemetry`) bundles the host-side layers behind
 a facade with a no-op `null()` form, so the runtime instruments

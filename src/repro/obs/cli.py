@@ -11,8 +11,8 @@ the same three-line way:
 `finish_run` is the ONE summary path (the three divergent printer blocks
 train/serve/fleet used to carry): it lands the result's scalar fields on
 the registry as gauges, prints the unified `format_summary` block, and
-finalizes the exporters (metrics.prom / manifest.json / trace.json) when
-`--metrics-dir` is set.
+finalizes the exporters (metrics.prom / manifest.json, and the profiler
+session that `--trace` opens) when `--metrics-dir` is set.
 """
 from __future__ import annotations
 
@@ -27,10 +27,12 @@ def add_obs_args(ap):
                          "(repro.obs; validate with "
                          "`python -m repro.obs.validate <dir>`)")
     ap.add_argument("--trace", action="store_true",
-                    help="record spans (window / rewire / rollback_replay / "
-                         "ckpt_write) and export Chrome-trace JSON to "
-                         "<metrics-dir>/trace.json — load in "
-                         "chrome://tracing")
+                    help="record a jax.profiler trace into "
+                         "<metrics-dir>/profile: host spans (window, "
+                         "fleet.admit, fleet.gather, fleet.bookkeep, "
+                         "fleet.retire, rewire, rollback_replay, "
+                         "ckpt_write) beside the device's ops, named by "
+                         "stage; load in Perfetto or TensorBoard")
     return ap
 
 

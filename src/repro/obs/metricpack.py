@@ -177,7 +177,8 @@ class MetricPack:
         """[F] float32 — call INSIDE the jitted chunk.  env keys (all
         optional except 'loss'): loss, grads, stats, carry, grad_norm,
         clip_factor, health."""
-        return jnp.stack([fn(env) for _, fn in self.fields])
+        with jax.named_scope("telemetry"):
+            return jnp.stack([fn(env) for _, fn in self.fields])
 
     def unpack(self, vec) -> dict:
         """Fetched [F] (or [..., F]) vector -> {name: float} (leading axes

@@ -131,8 +131,9 @@ class Registry:
     """Named metrics, get-or-create, optional labels.
 
     `counter("guard_faults_total")`, `gauge("loss")`,
-    `histogram("window_ms", buckets=...)`, plus `gauge("session_loss",
-    sid="u17")`-style labelled series.  Re-registering a name with a
+    `histogram("window_ms", buckets=...)`, plus `gauge("k", lbl="v")`-
+    style labelled series (keep a label's values bounded: each value is
+    one series for the life of the registry).  Re-registering a name with a
     different type raises — a name means one thing."""
 
     def __init__(self):

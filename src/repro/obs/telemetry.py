@@ -9,9 +9,12 @@ an interface the runtime can call UNCONDITIONALLY:
   files and records no spans: `emit` is a no-op, `span` costs one `if`.
 - `Telemetry.create(metrics_dir, ...)` turns on the exporters: events go
   to ``events.jsonl`` as they happen; `finalize()` writes the Prometheus
-  text exposition (``metrics.prom``), the run manifest
-  (``manifest.json``: config + git SHA + final registry snapshot), and —
-  when tracing — the Chrome-trace JSON (``trace.json``).
+  text exposition (``metrics.prom``) and the run manifest
+  (``manifest.json``: config + git SHA + final registry snapshot).  With
+  ``trace=True`` it also opens a `jax.profiler` session into
+  ``<metrics_dir>/profile`` and records spans as profiler annotations, so
+  host spans and device ops share one trace (Perfetto or TensorBoard load
+  it); `finalize()` closes the session.
 
 The in-jit `MetricPack` layer stays separate (`metricpack.py`) because it
 runs inside jitted chunks; `record_window` is the host-side half that
@@ -26,6 +29,8 @@ import subprocess
 import time
 import uuid
 from pathlib import Path
+
+import jax
 
 from repro.obs.events import SCHEMA_VERSION, EventLog, sanitize
 from repro.obs.registry import Registry
@@ -61,6 +66,7 @@ class Telemetry:
         self.config = config
         self._t_start = time.time()
         self._finalized = False
+        self._profiling = False
 
     # -- constructors -------------------------------------------------------
 
@@ -72,21 +78,27 @@ class Telemetry:
 
     @classmethod
     def create(cls, metrics_dir, trace: bool = False, run_id: str | None = None,
-               config: dict | None = None,
-               jax_annotations: bool = False) -> "Telemetry":
+               config: dict | None = None) -> "Telemetry":
         metrics_dir = Path(metrics_dir)
         metrics_dir.mkdir(parents=True, exist_ok=True)
         run_id = run_id or uuid.uuid4().hex[:12]
         t = cls(Registry(), EventLog(metrics_dir / "events.jsonl"),
-                Tracer(enabled=trace, jax_annotations=jax_annotations),
+                Tracer(enabled=trace, jax_annotations=trace),
                 metrics_dir, run_id, config)
+        if trace:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(str(metrics_dir / "profile"),
+                                     profiler_options=opts)
+            t._profiling = True
         t.emit("run_start", run_id=run_id)
         return t
 
     @property
     def active(self) -> bool:
-        """True when exporters write files (per-window events, per-session
-        gauges, and other proportional-cost instrumentation key off this)."""
+        """True when exporters write files (per-window events, the fleet's
+        per-session telemetry columns, and other proportional-cost
+        instrumentation key off this)."""
         return self.events is not None
 
     # -- the three verbs ----------------------------------------------------
@@ -127,9 +139,9 @@ class Telemetry:
 
     def finalize(self, final: dict | None = None,
                  extra_manifest: dict | None = None) -> dict | None:
-        """Write metrics.prom + manifest.json (+ trace.json), emit run_end,
-        close the event log.  Idempotent; returns the manifest (None for
-        null telemetry)."""
+        """Write metrics.prom + manifest.json, emit run_end, close the event
+        log and the profiler session.  Idempotent; returns the manifest
+        (None for null telemetry)."""
         if self.metrics_dir is None or self._finalized:
             return None
         self._finalized = True
@@ -149,8 +161,9 @@ class Telemetry:
         }
         (self.metrics_dir / "manifest.json").write_text(
             json.dumps(manifest, indent=2, allow_nan=False))
-        if self.tracer.enabled:
-            self.tracer.export_chrome(self.metrics_dir / "trace.json")
+        if self._profiling:
+            jax.profiler.stop_trace()
+            self._profiling = False
         if self.events is not None:
             self.events.close()
         return manifest

@@ -51,6 +51,7 @@ moments and stream position.  A fleet of 1 is bit-identical to the solo
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import time
 from typing import Any, Callable
 
@@ -97,19 +98,20 @@ def fleet_update_chunk(learner, opt, carry: Tree, opt_state: Tree,
         lambda c, o, x, y, u: online_update_chunk(learner, opt, c, o, x, y, u,
                                                   pack=pack)
     )(carry, opt_state, xs, ys, upd)
-    lf = live.astype(jnp.float32)
-    if pack is not None:
-        vec = m["packed"]                               # [S, F]
-        loss = vec[:, pack.names.index("loss")] * lf
-        ov_col = vec[:, pack.names.index("overflow")]
-        ov = jnp.where(jnp.isnan(ov_col), 0.0, ov_col) * lf
-        packed = jnp.concatenate(
-            [jnp.stack([lf, loss, ov], axis=-1), vec], axis=-1)
-        return carry, opt_state, packed
-    loss = jnp.asarray(m["loss"], jnp.float32) * lf
-    ov = (jnp.asarray(m["overflow"], jnp.float32) * lf
-          if "overflow" in m else jnp.zeros_like(lf))
-    packed = jnp.stack([lf, loss, ov], axis=-1)
+    with jax.named_scope("telemetry"):
+        lf = live.astype(jnp.float32)
+        if pack is not None:
+            vec = m["packed"]                           # [S, F]
+            loss = vec[:, pack.names.index("loss")] * lf
+            ov_col = vec[:, pack.names.index("overflow")]
+            ov = jnp.where(jnp.isnan(ov_col), 0.0, ov_col) * lf
+            packed = jnp.concatenate(
+                [jnp.stack([lf, loss, ov], axis=-1), vec], axis=-1)
+            return carry, opt_state, packed
+        loss = jnp.asarray(m["loss"], jnp.float32) * lf
+        ov = (jnp.asarray(m["overflow"], jnp.float32) * lf
+              if "overflow" in m else jnp.zeros_like(lf))
+        packed = jnp.stack([lf, loss, ov], axis=-1)
     return carry, opt_state, packed
 
 
@@ -180,49 +182,56 @@ class StreamFleet:
         self.carry, self.opt_state = jax.tree.map(lambda x: x.copy(), stack)
 
         self.sessions: dict[str, _Session] = {}
-        self._slot_sid: list[str | None] = [None] * S
+        self._free = list(range(S))     # heap of free slots
         self.windows = 0
 
         pack = self._pack
-        self._chunk = jax.jit(
-            lambda carry, opt_state, xs, ys, upd, live: fleet_update_chunk(
-                learner, opt, carry, opt_state, xs, ys, upd, live, pack=pack),
-            donate_argnums=(0, 1))
+
+        # named programs: the device trace and JAX's dispatch annotations
+        # say jit_fleet_chunk, jit_slot_write, jit_slot_read
+        def fleet_chunk(carry, opt_state, xs, ys, upd, live):
+            return fleet_update_chunk(learner, opt, carry, opt_state, xs, ys,
+                                      upd, live, pack=pack)
+
         # traced slot index: one compile serves every slot
-        self._write = jax.jit(
-            lambda stacked, tree, i: jax.tree.map(
+        def slot_write(stacked, tree, i):
+            return jax.tree.map(
                 lambda b, v: jax.lax.dynamic_update_index_in_dim(
-                    b, v.astype(b.dtype), i, 0), stacked, tree),
-            donate_argnums=(0,))
-        self._read = jax.jit(
-            lambda stacked, i: jax.tree.map(
+                    b, v.astype(b.dtype), i, 0), stacked, tree)
+
+        def slot_read(stacked, i):
+            return jax.tree.map(
                 lambda b: jax.lax.dynamic_index_in_dim(b, i, 0,
                                                        keepdims=False),
-                stacked))
+                stacked)
+
+        self._chunk = jax.jit(fleet_chunk, donate_argnums=(0, 1))
+        self._write = jax.jit(slot_write, donate_argnums=(0,))
+        self._read = jax.jit(slot_read)
 
     # -- slot management ----------------------------------------------------
 
     @property
     def n_live(self) -> int:
-        return sum(s is not None for s in self._slot_sid)
+        return len(self.sessions)
 
     def free_slots(self) -> list[int]:
-        return [i for i, s in enumerate(self._slot_sid) if s is None]
+        return sorted(self._free)
 
     def _claim(self, sid: str) -> int:
+        """The slot a joining session will take: the least free one."""
         if sid in self.sessions:
             raise ValueError(f"session {sid!r} already in the fleet")
-        free = self.free_slots()
-        if not free:
+        if not self._free:
             raise ValueError(f"fleet is full ({self.cfg.slots} slots); "
                              "evict a session first")
-        return free[0]
+        return self._free[0]
 
     def _install(self, sess: _Session, carry: Tree, opt_state: Tree):
         i = jnp.int32(sess.slot)
         self.carry = self._write(self.carry, carry, i)
         self.opt_state = self._write(self.opt_state, opt_state, i)
-        self._slot_sid[sess.slot] = sess.sid
+        heapq.heappop(self._free)       # the slot _claim gave out
         self.sessions[sess.sid] = sess
 
     def add_session(self, sid: str, stream: Callable[[int], tuple],
@@ -232,18 +241,20 @@ class StreamFleet:
         claimed slot.  No recompilation — the slot index is traced and the
         fleet shape is static."""
         slot = self._claim(sid)
-        if params is None:
-            carry = jax.tree.map(lambda x: x.copy(), self._template[0])
-            opt_state = jax.tree.map(lambda x: x.copy(), self._template[1])
-        else:
-            carry = self.learner.init(params, self.masks,
-                                      (self._x0, self._y0),
-                                      t_total=self._t_total)
-            opt_state = jax.jit(self.opt.init)(params)
-        self._install(_Session(sid, stream, slot), carry, opt_state)
-        self.obs.registry.counter("sessions_joined_total").inc()
-        self.obs.registry.gauge("sessions_live").set(self.n_live)
-        self.obs.emit("session_join", sid=sid, slot=slot)
+        with self.obs.span("fleet.admit", sid=sid, slot=slot):
+            if params is None:
+                carry = jax.tree.map(lambda x: x.copy(), self._template[0])
+                opt_state = jax.tree.map(lambda x: x.copy(),
+                                         self._template[1])
+            else:
+                carry = self.learner.init(params, self.masks,
+                                          (self._x0, self._y0),
+                                          t_total=self._t_total)
+                opt_state = jax.jit(self.opt.init)(params)
+            self._install(_Session(sid, stream, slot), carry, opt_state)
+            self.obs.registry.counter("sessions_joined_total").inc()
+            self.obs.registry.gauge("sessions_live").set(self.n_live)
+            self.obs.emit("session_join", sid=sid, slot=slot)
         return slot
 
     def remove(self, sid: str):
@@ -252,13 +263,15 @@ class StreamFleet:
         bounded values (its results are don't-care, but NaN/Inf drift on
         abandoned garbage is not worth carrying)."""
         sess = self.sessions.pop(sid)
-        self._slot_sid[sess.slot] = None
-        i = jnp.int32(sess.slot)
-        self.carry = self._write(self.carry, self._template[0], i)
-        self.opt_state = self._write(self.opt_state, self._template[1], i)
-        self.obs.registry.counter("sessions_left_total").inc()
-        self.obs.registry.gauge("sessions_live").set(self.n_live)
-        self.obs.emit("session_leave", sid=sid, slot=sess.slot)
+        with self.obs.span("fleet.retire", sid=sid, slot=sess.slot):
+            heapq.heappush(self._free, sess.slot)
+            i = jnp.int32(sess.slot)
+            self.carry = self._write(self.carry, self._template[0], i)
+            self.opt_state = self._write(self.opt_state, self._template[1],
+                                         i)
+            self.obs.registry.counter("sessions_left_total").inc()
+            self.obs.registry.gauge("sessions_live").set(self.n_live)
+            self.obs.emit("session_leave", sid=sid, slot=sess.slot)
 
     def slot_state(self, sid: str) -> tuple[Tree, Tree]:
         """(carry, opt_state) of one session, read out of the stack."""
@@ -296,15 +309,16 @@ class StreamFleet:
         same carry, same moments, same stream position.  Returns the slot."""
         store = self._store()
         slot = self._claim(sid)
-        like = {"carry": self._template[0], "opt": self._template[1],
-                "pos": jnp.int32(0), "upd": jnp.int32(0)}
-        tree, _ = load_session(store, sid, like)
-        sess = _Session(sid, stream, slot,
-                        pos=int(tree["pos"]), upd=int(tree["upd"]))
-        self._install(sess, tree["carry"], tree["opt"])
-        self.obs.registry.counter("sessions_resumed_total").inc()
-        self.obs.registry.gauge("sessions_live").set(self.n_live)
-        self.obs.emit("session_resume", sid=sid, slot=slot, pos=sess.pos)
+        with self.obs.span("fleet.admit", sid=sid, slot=slot):
+            like = {"carry": self._template[0], "opt": self._template[1],
+                    "pos": jnp.int32(0), "upd": jnp.int32(0)}
+            tree, _ = load_session(store, sid, like)
+            sess = _Session(sid, stream, slot,
+                            pos=int(tree["pos"]), upd=int(tree["upd"]))
+            self._install(sess, tree["carry"], tree["opt"])
+            self.obs.registry.counter("sessions_resumed_total").inc()
+            self.obs.registry.gauge("sessions_live").set(self.n_live)
+            self.obs.emit("session_resume", sid=sid, slot=slot, pos=sess.pos)
         return slot
 
     # -- the steady-state loop ----------------------------------------------
@@ -331,40 +345,45 @@ class StreamFleet:
         """Advance every live session by one k-step window + one optimizer
         update.  ONE dispatch, ONE packed [S, 3] readback — the loop stays
         free of per-session host syncs.  Returns {sid: {loss, overflow,
-        pos, upd}} for the window."""
+        pos, upd[, telemetry]}} for the window."""
         k = self.cfg.update_every
-        xs, ys, upd, live = self._gather(k)
+        span = self.obs.span
+        with span("fleet.gather", live=len(self.sessions)):
+            xs, ys, upd, live = self._gather(k)
         t0 = time.perf_counter()
-        with self.obs.span("window", window=self.windows, live=int(live.sum())):
+        with span("window", window=self.windows, live=int(live.sum())):
+            args = (jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(upd),
+                    jnp.asarray(live))
             self.carry, self.opt_state, packed = self._chunk(
-                self.carry, self.opt_state, jnp.asarray(xs), jnp.asarray(ys),
-                jnp.asarray(upd), jnp.asarray(live))
+                self.carry, self.opt_state, *args)
             pk = np.asarray(jax.device_get(packed))     # the single readback
         dt_ms = (time.perf_counter() - t0) * 1e3
+        tracer = self.obs.tracer
+        if tracer.enabled and "fleet_chunk" not in tracer.programs:
+            # once, after the first dispatch: the compile is cached
+            tracer.note_program("fleet_chunk", self._chunk, self.carry,
+                                self.opt_state, *args)
         self.windows += 1
         reg = self.obs.registry
         reg.counter("fleet_windows_total").inc()
         reg.histogram("fleet_window_ms").observe(dt_ms)
         out = {}
-        for sess in self.sessions.values():
-            sess.pos += k
-            sess.upd += 1
-            sess.loss = float(pk[sess.slot, 1])
-            sess.overflow = float(pk[sess.slot, 2])
-            out[sess.sid] = {"loss": sess.loss, "overflow": sess.overflow,
-                             "pos": sess.pos, "upd": sess.upd}
-            if self._pack is not None:
-                # the [3:] tail is the slot's full MetricPack vector —
-                # labelled per-session gauges, no extra readback
-                m = self._pack.unpack(pk[sess.slot, 3:])
-                out[sess.sid]["telemetry"] = m
-                for name in ("loss", "grad_norm", "act_sparsity"):
-                    v = m.get(name)
-                    if v is not None and not np.isnan(v):
-                        reg.gauge(f"session_{name}", sid=sess.sid).set(v)
-                reg.gauge("session_pos", sid=sess.sid).set(sess.pos)
-        self.obs.emit("fleet_window", window=self.windows,
-                      live=int(live.sum()), dt_ms=dt_ms)
+        with span("fleet.bookkeep", live=len(self.sessions)):
+            for sess in self.sessions.values():
+                sess.pos += k
+                sess.upd += 1
+                sess.loss = float(pk[sess.slot, 1])
+                sess.overflow = float(pk[sess.slot, 2])
+                out[sess.sid] = {"loss": sess.loss,
+                                 "overflow": sess.overflow,
+                                 "pos": sess.pos, "upd": sess.upd}
+                if self._pack is not None:
+                    # the [3:] tail is the slot's full MetricPack vector,
+                    # no extra readback
+                    out[sess.sid]["telemetry"] = self._pack.unpack(
+                        pk[sess.slot, 3:])
+            self.obs.emit("fleet_window", window=self.windows,
+                          live=int(live.sum()), dt_ms=dt_ms)
         return out
 
     def report(self) -> dict:

@@ -50,7 +50,9 @@ def stream_grads(learner, carry: Tree, xs: jax.Array, ys: jax.Array):
         return c, out.stats
 
     carry, stats = jax.lax.scan(body, carry, (xs, ys))
-    return carry, carry["loss"], learner.grads(carry), stats
+    with jax.named_scope("grad_readout"):
+        grads = learner.grads(carry)
+    return carry, carry["loss"], grads, stats
 
 
 def online_update_chunk(learner, opt, carry: Tree, opt_state: Tree,
@@ -67,9 +69,10 @@ def online_update_chunk(learner, opt, carry: Tree, opt_state: Tree,
     chunk's carry/opt_state outputs are bit-identical to pack=None
     (tests/test_obs.py pins this)."""
     carry, loss, grads, stats = stream_grads(learner, carry, xs, ys)
-    params, opt_state = opt.update(grads, opt_state,
-                                   learner.params_of(carry), upd)
-    carry = learner.reset_grads(carry, params)
+    with jax.named_scope("optimizer"):
+        params, opt_state = opt.update(grads, opt_state,
+                                       learner.params_of(carry), upd)
+        carry = learner.reset_grads(carry, params)
     if pack is not None:
         packed = pack.pack({"loss": loss, "grads": grads, "stats": stats,
                             "carry": carry})

@@ -2,13 +2,14 @@
 — instrumented chunks are bit-identical to bare ones for the solo and the
 vmapped fleet paths, all window scalars cost one packed readback — and the
 host-side layers round-trip: schema-versioned JSONL events, fixed-bucket
-histogram percentiles pinned against numpy, nested spans with Chrome-trace
-export, guard event emission under injected faults, and the benchmark
+histogram percentiles pinned against numpy, nested spans with parents and
+self times, guard event emission under injected faults, and the benchmark
 trajectory aggregator's schema checks."""
 import json
 import math
 import os
 import sys
+import time
 from pathlib import Path
 
 import jax
@@ -22,6 +23,7 @@ from repro.core.learner import LearnerSpec, make_learner
 from repro.obs import (KIND_FIELDS, SCHEMA_VERSION, EventLog, Histogram,
                        MetricPack, Registry, SchemaError, Telemetry, Tracer,
                        format_summary, read_events)
+from repro.obs import trace as trace_mod
 from repro.obs.validate import validate_dir
 from repro.optim import make_optimizer
 from repro.runtime.fleet import FleetConfig, StreamFleet, fleet_update_chunk
@@ -216,9 +218,13 @@ def test_trainer_with_telemetry_is_bitwise_identical(tmp_path):
         for f in ("loss", "grad_norm", "act_sparsity", "bwd_sparsity",
                   "kb_min", "kb_mean", "kb_max", "dt_ms"):
             assert isinstance(w[f], (int, float)), f
-    trace = json.loads((tmp_path / "m" / "trace.json").read_text())
-    spans = [e for e in trace["traceEvents"] if e["name"] == "window"]
+    spans = [s for s in obs.tracer.spans if s["name"] == "window"]
     assert len(spans) == out_b["updates"]
+    assert all(s["parent"] is None and s["dur_ns"] > 0 for s in spans)
+    # --trace records a profiler trace, not a span export of its own
+    assert not (tmp_path / "m" / "trace.json").exists()
+    assert list((tmp_path / "m" / "profile").glob(
+        "plugins/profile/*/*.xplane.pb"))
     man = json.loads((tmp_path / "m" / "manifest.json").read_text())
     assert man["run_id"] == "t0" and man["config"]["test"] is True
     assert man["metrics"]["loss"] == wins[-1]["loss"]
@@ -256,8 +262,8 @@ def test_guard_events_under_fault_plan(tmp_path):
 
 def test_fleet_session_lifecycle_events(tmp_path):
     """Fleet with active telemetry: join/evict/resume/leave each emit
-    their event, per-session labelled gauges land, and step_window returns
-    the decoded per-session telemetry tail."""
+    their event, step_window returns the decoded per-session telemetry
+    tail, and no registry series is labelled by session id."""
     cfg, masks, learner, opt, params = _setup()
     obs = Telemetry.create(tmp_path / "m")
     fleet = StreamFleet(FleetConfig(slots=2, update_every=2,
@@ -283,8 +289,9 @@ def test_fleet_session_lifecycle_events(tmp_path):
     assert reg.counter("sessions_joined_total").value == 2
     assert reg.counter("sessions_evicted_total").value == 1
     assert reg.counter("sessions_resumed_total").value == 1
-    assert reg.gauge("session_loss", sid="a").value == np.float32(
-        stats2["a"]["loss"])                 # last-write-wins: window 2
+    assert stats2["a"]["telemetry"]["loss"] == stats2["a"]["loss"]
+    assert stats2["a"]["pos"] == 4 and stats2["a"]["upd"] == 2
+    assert "sid=" not in reg.to_prometheus()
     rep = fleet.report()
     assert rep["window_ms_p50"] > 0 and rep["window_ms_p99"] > 0
 
@@ -385,33 +392,43 @@ def test_registry_semantics_and_prometheus():
 
 
 def test_tracer_nesting_and_chrome_export(tmp_path):
+    """The span record: parent ids, self time (duration less the children),
+    starts on the profiler's clock (`time.time_ns`), depth and arguments;
+    a tracer that is on is the process's tracer; one that is off records
+    nothing."""
+    before = time.time_ns()
     tr = Tracer(enabled=True)
+    assert trace_mod.current() is tr
     with tr.span("window", update=0):
         with tr.span("rewire", frac=np.float32(0.2)):
-            pass
+            time.sleep(0.002)
         with tr.span("ckpt_write"):
             pass
     assert [s["name"] for s in tr.spans] == ["rewire", "ckpt_write",
                                              "window"]
     by = {s["name"]: s for s in tr.spans}
-    assert by["window"]["depth"] == 0
+    win = by["window"]
+    assert win["depth"] == 0 and win["parent"] is None
     assert by["rewire"]["depth"] == 1 and by["ckpt_write"]["depth"] == 1
+    assert by["rewire"]["parent"] == by["ckpt_write"]["parent"] == win["id"]
+    assert by["rewire"]["args"] == {"frac": pytest.approx(0.2)}
+    assert before <= win["start_ns"] <= time.time_ns()
     # interval containment: children nest inside the parent
     for child in ("rewire", "ckpt_write"):
-        assert by["window"]["ts"] <= by[child]["ts"]
-        assert (by[child]["ts"] + by[child]["dur"]
-                <= by["window"]["ts"] + by["window"]["dur"] + 1e-6)
-    p = tr.export_chrome(tmp_path / "trace.json")
-    doc = json.loads(p.read_text())
-    assert doc["displayTimeUnit"] == "ms"
-    ev = {e["name"]: e for e in doc["traceEvents"]}
-    assert ev["window"]["ph"] == "X" and ev["rewire"]["args"] == {
-        "frac": pytest.approx(0.2)}
+        c = by[child]
+        assert win["start_ns"] <= c["start_ns"]
+        assert (c["start_ns"] + c["dur_ns"]
+                <= win["start_ns"] + win["dur_ns"])
+        assert c["self_ns"] == c["dur_ns"]          # leaves
+    assert by["rewire"]["dur_ns"] >= 2_000_000
+    assert win["self_ns"] == (win["dur_ns"] - by["rewire"]["dur_ns"]
+                              - by["ckpt_write"]["dur_ns"])
+    assert not hasattr(tr, "export_chrome")
 
     off = Tracer(enabled=False)
     with off.span("window"):
         pass
-    assert off.spans == []
+    assert len(off.spans) == 0 and trace_mod.current() is tr
 
 
 def test_null_telemetry_is_inert_but_counts(tmp_path):
