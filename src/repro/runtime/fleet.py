@@ -31,6 +31,13 @@ Slot-based continuous batching, same discipline as `runtime/serving.py`:
   `optimization_barrier`, which would break fleet-of-1 bit-identity with
   the solo trainer.  A mask-only consumer of the scalar metrics is
   measured clean; tests/test_fleet.py pins this.);
+- joins from the template and leaves touch no device buffer: they mark
+  their slot pending on the host, and before the next window ONE donated
+  program (`jit_slot_reset`) writes the template into every pending slot
+  of both stacks at once.  Every read of the stacks (`carry`,
+  `opt_state`, `slot_state`, `evict`) applies pending resets first, and an
+  explicit install (`add_session(params=)`, `resume`) cancels its slot's,
+  so what any read sees is what a per-session write would have left;
 - idle sessions EVICT their full {carry, opt state, stream position,
   update count} to the session-keyed checkpoint store
   (`repro.checkpoint.save_session`) and later resume bit-for-bit — the
@@ -148,7 +155,10 @@ class StreamFleet:
     slot index — no recompile), `evict(sid)` writes the session's full
     state to the store and frees its slot, `resume(sid, stream)` loads it
     back bit-for-bit into any free slot, `step_window()` advances every
-    live session by one k-step window.
+    live session by one k-step window.  A join from the template and a
+    leave only mark their slot pending; `step_window` resets all pending
+    slots with one `jit_slot_reset` dispatch before its chunk, and any
+    read of `carry`, `opt_state` or `slot_state` applies them first.
     """
 
     def __init__(self, cfg: FleetConfig, learner, opt, params: Tree,
@@ -179,16 +189,18 @@ class StreamFleet:
         # leaf to own its storage (same trick as runtime/serving.py).
         stack = jax.jit(lambda t: jax.tree.map(
             lambda x: jnp.repeat(x[None], S, 0), t))((carry0, opt0))
-        self.carry, self.opt_state = jax.tree.map(lambda x: x.copy(), stack)
+        self._carry, self._opt_state = jax.tree.map(lambda x: x.copy(),
+                                                    stack)
 
         self.sessions: dict[str, _Session] = {}
         self._free = list(range(S))     # heap of free slots
+        self._pending: set[int] = set() # slots owed a template reset
         self.windows = 0
 
         pack = self._pack
 
         # named programs: the device trace and JAX's dispatch annotations
-        # say jit_fleet_chunk, jit_slot_write, jit_slot_read
+        # say jit_fleet_chunk, jit_slot_write, jit_slot_read, jit_slot_reset
         def fleet_chunk(carry, opt_state, xs, ys, upd, live):
             return fleet_update_chunk(learner, opt, carry, opt_state, xs, ys,
                                       upd, live, pack=pack)
@@ -205,9 +217,47 @@ class StreamFleet:
                                                        keepdims=False),
                 stacked)
 
+        # a fixed-shape [S] mask: one compile serves any set of slots; the
+        # select runs in place on the donated stacks
+        def slot_reset(carry, opt_state, mask, template):
+            def put(b, t):
+                m = mask.reshape((-1,) + (1,) * t.ndim)
+                return jnp.where(m, t[None], b)
+            return (jax.tree.map(put, carry, template[0]),
+                    jax.tree.map(put, opt_state, template[1]))
+
         self._chunk = jax.jit(fleet_chunk, donate_argnums=(0, 1))
         self._write = jax.jit(slot_write, donate_argnums=(0,))
         self._read = jax.jit(slot_read)
+        self._reset = jax.jit(slot_reset, donate_argnums=(0, 1))
+
+    # -- the slot-stacked state ---------------------------------------------
+
+    @property
+    def carry(self) -> Tree:
+        """The slot-stacked learner carry, pending resets applied."""
+        self._apply_resets()
+        return self._carry
+
+    @property
+    def opt_state(self) -> Tree:
+        """The slot-stacked optimizer state, pending resets applied."""
+        self._apply_resets()
+        return self._opt_state
+
+    def _apply_resets(self):
+        """Write the template into every pending slot: one dispatch, not
+        waited on."""
+        if not self._pending:
+            return
+        n = len(self._pending)
+        with self.obs.span("fleet.slot_reset", slots=n):
+            mask = np.zeros((self.cfg.slots,), bool)
+            mask[list(self._pending)] = True
+            self._carry, self._opt_state = self._reset(
+                self._carry, self._opt_state, mask, self._template)
+            self._pending.clear()
+            self.obs.registry.counter("fleet_slot_resets_total").inc(n)
 
     # -- slot management ----------------------------------------------------
 
@@ -227,31 +277,40 @@ class StreamFleet:
                              "evict a session first")
         return self._free[0]
 
-    def _install(self, sess: _Session, carry: Tree, opt_state: Tree):
-        i = jnp.int32(sess.slot)
-        self.carry = self._write(self.carry, carry, i)
-        self.opt_state = self._write(self.opt_state, opt_state, i)
+    def _install(self, sess: _Session, carry: Tree | None = None,
+                 opt_state: Tree | None = None):
+        """Give `sess` the slot `_claim` gave out.  With no state, the slot
+        is marked for the next template reset (no device work); with a
+        state, its pending reset is cancelled and the state written into
+        the slot directly (it differs per session)."""
+        if carry is None:
+            self._pending.add(sess.slot)
+        else:
+            self._pending.discard(sess.slot)    # the write covers the slot
+            i = jnp.int32(sess.slot)
+            self._carry = self._write(self._carry, carry, i)
+            self._opt_state = self._write(self._opt_state, opt_state, i)
         heapq.heappop(self._free)       # the slot _claim gave out
         self.sessions[sess.sid] = sess
 
     def add_session(self, sid: str, stream: Callable[[int], tuple],
                     params: Tree | None = None) -> int:
         """Join a fresh session mid-flight: new carry + opt state from
-        `params` (default: a copy of the fleet's template).  Returns the
-        claimed slot.  No recompilation — the slot index is traced and the
-        fleet shape is static."""
+        `params`, written into the slot now, or by default the fleet's
+        template, written with every other pending slot by the next
+        window's one reset.  Returns the claimed slot.  No recompilation —
+        the slot index is traced and the fleet shape is static."""
         slot = self._claim(sid)
         with self.obs.span("fleet.admit", sid=sid, slot=slot):
+            sess = _Session(sid, stream, slot)
             if params is None:
-                carry = jax.tree.map(lambda x: x.copy(), self._template[0])
-                opt_state = jax.tree.map(lambda x: x.copy(),
-                                         self._template[1])
+                self._install(sess)
             else:
                 carry = self.learner.init(params, self.masks,
                                           (self._x0, self._y0),
                                           t_total=self._t_total)
                 opt_state = jax.jit(self.opt.init)(params)
-            self._install(_Session(sid, stream, slot), carry, opt_state)
+                self._install(sess, carry, opt_state)
             self.obs.registry.counter("sessions_joined_total").inc()
             self.obs.registry.gauge("sessions_live").set(self.n_live)
             self.obs.emit("session_join", sid=sid, slot=slot)
@@ -259,16 +318,14 @@ class StreamFleet:
 
     def remove(self, sid: str):
         """Leave without persisting (abandoned session).  The freed slot is
-        reset to the template state so the now-dead lane keeps grinding on
-        bounded values (its results are don't-care, but NaN/Inf drift on
-        abandoned garbage is not worth carrying)."""
+        marked for the next window's template reset, so the now-dead lane
+        keeps grinding on bounded values (its results are don't-care, but
+        NaN/Inf drift on abandoned garbage is not worth carrying); no
+        device work here."""
         sess = self.sessions.pop(sid)
         with self.obs.span("fleet.retire", sid=sid, slot=sess.slot):
             heapq.heappush(self._free, sess.slot)
-            i = jnp.int32(sess.slot)
-            self.carry = self._write(self.carry, self._template[0], i)
-            self.opt_state = self._write(self.opt_state, self._template[1],
-                                         i)
+            self._pending.add(sess.slot)
             self.obs.registry.counter("sessions_left_total").inc()
             self.obs.registry.gauge("sessions_live").set(self.n_live)
             self.obs.emit("session_leave", sid=sid, slot=sess.slot)
@@ -348,21 +405,22 @@ class StreamFleet:
         pos, upd[, telemetry]}} for the window."""
         k = self.cfg.update_every
         span = self.obs.span
+        self._apply_resets()
         with span("fleet.gather", live=len(self.sessions)):
             xs, ys, upd, live = self._gather(k)
         t0 = time.perf_counter()
         with span("window", window=self.windows, live=int(live.sum())):
             args = (jnp.asarray(xs), jnp.asarray(ys), jnp.asarray(upd),
                     jnp.asarray(live))
-            self.carry, self.opt_state, packed = self._chunk(
-                self.carry, self.opt_state, *args)
+            self._carry, self._opt_state, packed = self._chunk(
+                self._carry, self._opt_state, *args)
             pk = np.asarray(jax.device_get(packed))     # the single readback
         dt_ms = (time.perf_counter() - t0) * 1e3
         tracer = self.obs.tracer
         if tracer.enabled and "fleet_chunk" not in tracer.programs:
             # once, after the first dispatch: the compile is cached
-            tracer.note_program("fleet_chunk", self._chunk, self.carry,
-                                self.opt_state, *args)
+            tracer.note_program("fleet_chunk", self._chunk, self._carry,
+                                self._opt_state, *args)
         self.windows += 1
         reg = self.obs.registry
         reg.counter("fleet_windows_total").inc()

@@ -180,3 +180,146 @@ def test_session_store_namespacing_and_validation(tmp_path):
     with pytest.raises(CheckpointError):
         load_session(str(tmp_path), "never-saved", tree)
     assert list_sessions(str(tmp_path)) == ["user-1"]
+
+
+# -- template joins and leaves: one batched slot reset per window -----------
+
+def _same_arrays(a, b):
+    return all(x is y for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+def _counting(fleet, name):
+    """Wrap the fleet's jitted program `name` to count its dispatches."""
+    calls = []
+    jitted = getattr(fleet, name)
+
+    def wrapped(*args):
+        calls.append(1)
+        return jitted(*args)
+    setattr(fleet, name, wrapped)
+    return calls
+
+
+def test_template_joins_and_leaves_make_one_reset_per_window():
+    """Joins from the template and leaves touch no device buffer; the next
+    window makes exactly one `jit_slot_reset` dispatch for all of them, and
+    `fleet_slot_resets_total` counts the distinct slots it wrote."""
+    cfg, masks, learner, opt, params = _setup()
+    fleet = StreamFleet(FleetConfig(slots=4, update_every=2), learner, opt,
+                        params, masks, example=_stream()(0))
+    mask = np.zeros((4,), bool)
+    text = fleet._reset.lower(fleet._carry, fleet._opt_state, mask,
+                              fleet._template).as_text()
+    assert "@jit_slot_reset" in text
+    resets = _counting(fleet, "_reset")
+    writes = _counting(fleet, "_write")
+    counter = fleet.obs.registry.counter("fleet_slot_resets_total")
+    stacks = (fleet._carry, fleet._opt_state)
+    for i in range(3):
+        fleet.add_session(f"a{i}", _stream(salt=i))
+    # no device work on admission: the stacks are the same arrays
+    assert _same_arrays((fleet._carry, fleet._opt_state), stacks)
+    fleet.step_window()
+    assert len(resets) == 1 and counter.value == 3
+    fleet.remove("a0")
+    fleet.remove("a1")
+    stacks = (fleet._carry, fleet._opt_state)
+    fleet.add_session("b0", _stream(salt=5))    # slot 0, already pending
+    fleet.add_session("b1", _stream(salt=6))    # slot 1
+    fleet.add_session("b2", _stream(salt=7))    # slot 3
+    assert _same_arrays((fleet._carry, fleet._opt_state), stacks)
+    fleet.step_window()
+    assert len(resets) == 2 and counter.value == 3 + 3
+    fleet.step_window()                         # nothing pending
+    assert len(resets) == 2 and counter.value == 6
+    assert writes == []
+
+
+def test_template_joins_equal_explicit_joins_bitwise():
+    """Sessions joining from the template (reset in one batch per window)
+    end bit-identical, slot by slot, to the same sessions joining with
+    `params=` the template's parameters (written slot by slot at once)."""
+    cfg, masks, learner, opt, params = _setup()
+
+    def run(explicit):
+        fleet = StreamFleet(FleetConfig(slots=4, update_every=2), learner,
+                            opt, params, masks, example=_stream()(0))
+        kw = {"params": params} if explicit else {}
+        for i in range(3):
+            fleet.add_session(f"u{i}", _stream(salt=i), **kw)
+        for w in range(6):
+            if w == 2:
+                fleet.remove("u1")
+                fleet.add_session("g0", _stream(salt=10), **kw)
+                fleet.add_session("g1", _stream(salt=11), **kw)
+            if w == 4:
+                fleet.remove("u0")
+                fleet.remove("g1")
+                fleet.add_session("g2", _stream(salt=12), **kw)
+            fleet.step_window()
+        fleet.remove("g0")
+        return fleet, {sid: fleet.slot_state(sid) for sid in fleet.sessions}
+
+    f_t, per_t = run(explicit=False)
+    f_e, per_e = run(explicit=True)
+    assert sorted(per_t) == sorted(per_e) == ["g2", "u2"]
+    for sid in per_t:
+        assert f_t.sessions[sid].slot == f_e.sessions[sid].slot
+        _tree_equal(per_t[sid], per_e[sid])
+    _tree_equal((f_t.carry, f_t.opt_state), (f_e.carry, f_e.opt_state))
+
+
+def test_reads_before_the_next_window_see_the_template():
+    """`slot_state` and `fleet.carry` read between a join or leave and the
+    next window show the template in the pending slots."""
+    cfg, masks, learner, opt, params = _setup()
+    fleet = StreamFleet(FleetConfig(slots=3, update_every=2), learner, opt,
+                        params, masks, example=_stream()(0))
+    t_carry, t_opt = fleet._template
+    fleet.add_session("a", _stream(salt=1))
+    fleet.add_session("b", _stream(salt=2))
+    _tree_equal(fleet.slot_state("a"), (t_carry, t_opt))
+    for _ in range(2):
+        fleet.step_window()
+    moved = fleet.slot_state("a")[0]["params"]
+    assert any((np.asarray(x) != np.asarray(y)).any() for x, y in zip(
+        jax.tree.leaves(moved), jax.tree.leaves(t_carry["params"])))
+    fleet.remove("a")                           # slot 0 pending
+    assert fleet._pending == {0}
+    carry, opt_state = fleet.carry, fleet.opt_state
+    assert not fleet._pending
+    _tree_equal(jax.tree.map(lambda x: x[0], (carry, opt_state)),
+                (t_carry, t_opt))
+    fleet.remove("b")                           # slot 1 pending
+    fleet.add_session("c", _stream(salt=3))     # slot 0
+    assert fleet.sessions["c"].slot == 0 and fleet._pending == {0, 1}
+    _tree_equal(fleet.slot_state("c"), (t_carry, t_opt))
+    _tree_equal(jax.tree.map(lambda x: x[1], fleet.opt_state), t_opt)
+
+
+def test_resume_into_a_pending_slot_keeps_its_loaded_state(tmp_path):
+    """A session resumed into a slot whose template reset is still pending
+    keeps its loaded state bitwise, through the next window too."""
+    cfg, masks, learner, opt, params = _setup()
+    stream = _stream(salt=4)
+
+    def fleet_with_a():
+        fleet = StreamFleet(FleetConfig(slots=2, update_every=2,
+                                        store_dir=str(tmp_path / "store")),
+                            learner, opt, params, masks, example=stream(0))
+        fleet.add_session("a", stream)
+        for _ in range(2):
+            fleet.step_window()
+        return fleet
+
+    ref = fleet_with_a()
+    fleet = fleet_with_a()
+    before = fleet.slot_state("a")
+    fleet.evict("a")                            # slot 0 pending
+    assert fleet._pending == {0}
+    assert fleet.resume("a", stream) == 0
+    assert not fleet._pending
+    _tree_equal(fleet.slot_state("a"), before)
+    for f in (ref, fleet):
+        f.step_window()
+    _tree_equal(fleet.slot_state("a"), ref.slot_state("a"))

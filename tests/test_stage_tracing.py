@@ -1,7 +1,7 @@
-"""Stage tracing: the fleet's host spans (admission, input gather, window,
-bookkeeping, retirement) with parents and self times, the map from the
-compiled update chunk's instructions to its named scopes, and the
-benchmark's per-stage readers that turn both into per-window times."""
+"""Stage tracing: the fleet's host spans (admission, slot reset, input
+gather, window, bookkeeping, retirement) with parents and self times, the
+map from the compiled update chunk's instructions to its named scopes, and
+the benchmark's per-stage readers that turn both into per-window times."""
 import re
 import sys
 from pathlib import Path
@@ -32,7 +32,8 @@ DEVICE_READERS = tuple(f"{s}_ms_per_window" for s in DEVICE_STAGES) + (
 HOST_READERS = {"admission_ms_per_window": "fleet.admit",
                 "input_gather_ms_per_window": "fleet.gather",
                 "bookkeeping_ms_per_window": "fleet.bookkeep",
-                "retire_ms_per_window": "fleet.retire"}
+                "retire_ms_per_window": "fleet.retire",
+                "slot_reset_ms_per_window": "fleet.slot_reset"}
 READERS = DEVICE_READERS + tuple(HOST_READERS) + (
     "untraced_idle_ms_per_window",)
 SLOTS, B, K = 16, 4, 8
@@ -115,6 +116,30 @@ def test_fleet_spans_per_window_and_per_session(traced):
         assert s["self_ns"] == s["dur_ns"] - sum(k["dur_ns"] for k in kids)
 
 
+def test_fleet_slot_reset_span_per_window_with_pending_slots(traced):
+    """One `fleet.slot_reset` span before each window that had pending
+    slots, its `slots` the number written, under no other fleet span."""
+    tr, fleet, joined, left = traced
+    spans = list(tr.spans)
+    by_id = {s["id"]: s for s in spans}
+    resets = [s for s in spans if s["name"] == "fleet.slot_reset"]
+    # window 1: every slot joined; then each window: 3 leave, 3 join there
+    assert [s["args"]["slots"] for s in resets] == [SLOTS, 3, 3]
+    assert all(type(s["args"]["slots"]) is int for s in resets)
+    assert len(resets) == fleet.windows
+    assert fleet.obs.registry.counter(
+        "fleet_slot_resets_total").value == SLOTS + 3 + 3
+    for s in resets:
+        p = s["parent"]
+        while p is not None:
+            assert not by_id[p]["name"].startswith("fleet."), by_id[p]
+            p = by_id[p]["parent"]
+    # each reset comes right before its window's input gather
+    order = [s["name"] for s in spans
+             if s["name"] in ("fleet.slot_reset", "fleet.gather")]
+    assert order == ["fleet.slot_reset", "fleet.gather"] * 3
+
+
 def test_fleet_with_tracer_off_records_nothing():
     tr = Tracer(enabled=False)
     fleet = _fleet(tr)
@@ -183,8 +208,8 @@ def _read(name, ctx):
 def _synthetic(monkeypatch):
     """A tracer holding a chunk map and the spans of a set-up window and 3
     traced windows, and the trace summary that goes with them: between
-    windows 10 ms of bookkeeping, 5 of retirement, 20 of admission, 4 of
-    input gather, and 1 ms idle under no span."""
+    windows 10 ms of bookkeeping, 5 of retirement, 20 of admission, 2 of
+    slot reset, 4 of input gather, and 1 ms idle under no span."""
     monkeypatch.setattr(trace_mod, "_current", None)
     tr = Tracer(enabled=True)
     assert trace_mod.current() is tr
@@ -199,7 +224,8 @@ def _synthetic(monkeypatch):
     chunk_s = sum(v for k, v in ops.items() if k in tr.programs["fleet_chunk"])
     t = 0
     for w in range(4):               # window 0 is set-up's, not traced
-        for name, dur in (("fleet.admit", 20), ("fleet.gather", 4),
+        for name, dur in (("fleet.admit", 20), ("fleet.slot_reset", 2),
+                          ("fleet.gather", 4),
                           ("window", 1000), ("fleet.bookkeep", 10),
                           ("fleet.retire", 5)):
             tr.spans.append({"id": len(tr.spans), "parent": None,
@@ -209,7 +235,7 @@ def _synthetic(monkeypatch):
         t += MS                      # under no span
     trace = {"ops": ops, "gaps": {"(no host span)": 2e-3, "window": 0.1},
              "chunk": {"program": "jit_fleet_chunk(1)", "runs": 3,
-                       "busy_s": chunk_s, "idle_between_s": 2 * 40e-3}}
+                       "busy_s": chunk_s, "idle_between_s": 2 * 42e-3}}
     return {"trace": trace, "spec": {"trace_windows": 3}}, chunk_s
 
 
@@ -229,11 +255,24 @@ def test_host_readers_add_up_to_the_gap(monkeypatch):
     assert got == pytest.approx({"admission_ms_per_window": 20.0,
                                  "input_gather_ms_per_window": 4.0,
                                  "bookkeeping_ms_per_window": 10.0,
-                                 "retire_ms_per_window": 5.0})
+                                 "retire_ms_per_window": 5.0,
+                                 "slot_reset_ms_per_window": 2.0})
     untraced = _read("untraced_idle_ms_per_window", ctx)
     assert untraced == pytest.approx(1.0)
     assert sum(got.values()) + untraced == pytest.approx(
         _read("host_gap_ms_per_window", ctx))
+
+
+def test_slot_reset_reader_is_none_where_no_reset_span_exists(monkeypatch):
+    """A program that opens no `fleet.slot_reset` span (one that resets
+    slots one by one) gives no reading rather than 0."""
+    ctx, _ = _synthetic(monkeypatch)
+    tr = trace_mod.current()
+    kept = [s for s in tr.spans if s["name"] != "fleet.slot_reset"]
+    tr.spans.clear()
+    tr.spans.extend(kept)
+    assert _read("admission_ms_per_window", ctx) == pytest.approx(20.0)
+    assert _read("slot_reset_ms_per_window", ctx) is None
 
 
 @pytest.mark.parametrize("name", READERS)
