@@ -1,12 +1,34 @@
 """How `correct` is decided: what the timed path produced against the plain
-reference (`bench/reference.py`), run in float64 on the host's CPU.
+reference, run in float64 on the host's CPU.
 
-A cell's record (`Cell.check_record()`) holds, for each checked stream, its
-inputs, the window losses the program read back, and, where the program's
-state could be read at the window boundaries, the first gradient as the
-optimizer got it (AdamW's first moment after one update, over 1 - b1) and the
-parameters after the last checked window.  Three numbers are compared, each
-the worst over the streams:
+The reference is found by name, from the configuration's `model.cell`:
+`bench/references/<cell>.py`.  A cell of a new architecture brings its own
+module there; nothing here knows a cell's equations.  The module exposes
+
+  INPUTS          the names of a stream's input arrays in the record
+  make_reference(model, windows, matmul="highest", **fault) -> run
+                  `run(shared, stream)` for one stream: shared is
+                  {"params0", "masks"} (canonical leaves), stream holds the
+                  INPUTS and optionally "start" (the state at the first
+                  step, in the module's own layout) and "alt" (one of the
+                  module's alternatives); it returns {"loss" [windows],
+                  "grad1" {leaf}, "params" {leaf}, ...}.  `matmul` is
+                  "highest" or "bf16x3" (the control's precision); a fault
+                  keyword such as `drop_half_batch` plants a fault.
+  alternatives(out) -> [alt, ...]   optional: other exact outcomes of one
+                  stream, from its run's host outputs (near-ties), against
+                  which the stream is judged too
+
+A cell's record (`Cell.check_record()`) is a list of parts.  A part is
+{"part", "model", "params0", "masks", "windows", "streams"}; each stream
+holds its inputs, the window losses the program read back and, where the
+program's state could be read at the window boundaries, the first gradient
+as the optimizer got it (from AdamW's first moment after one update) and
+the parameters after the last checked window.  A stream with a "start" is
+compared from that state: its parameters' change is taken from the start's
+parameters.  Three numbers are compared in each part, each the worst over
+its streams, and named `<part>.<number>` (the number alone for the part
+named ""):
 
   loss_gap    max over windows of |loss - ref| / |ref|
   grad1_gap   max over leaves of | |g| - |g_ref| | / max(|g_ref|, median)
@@ -22,23 +44,43 @@ gradient is zero to rounding in a later window takes an AdamW step of up to
 the learning rate from round-off alone, so the worst leaf's change swings
 from seed to seed in sound float32 runs.  The worst leaf's reading is
 reported beside the check (`change_gap_worst_leaf`), not compared.
-
-The EGRU's Heaviside makes a unit's event depend on the sign of its
-pre-activation v.  Where the reference finds |v| under `TIE_MARGIN`, float32
-rounding may decide the event either way, and both outcomes are exact
-results.  The reference is then also run with each such event flipped, and
-the stream is judged against whichever outcome it matches best.
 """
 from __future__ import annotations
 
 import functools
+import importlib.util
 import json
+import sys
+from pathlib import Path
 
 import numpy as np
 
 NUMBERS = ("loss_gap", "grad1_gap", "change_gap_median")
-TIE_MARGIN = 1e-5     # |v| under this: float32 may take the event either way
-MAX_TIES = 4          # near-ties tried per stream
+REFERENCES = Path(__file__).resolve().parent / "references"
+
+
+def reference_path(cell: str) -> Path:
+    return REFERENCES / f"{cell}.py"
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path):
+    spec = importlib.util.spec_from_file_location(
+        f"bench_reference_{path.stem.replace('-', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference_module(cell: str):
+    """The reference of a configuration's cell; exits with code 2 where
+    there is none."""
+    path = reference_path(cell)
+    if not path.is_file():
+        print(f"bench: missing reference {path.name} for cell {cell!r} "
+              f"(bench/references/)", file=sys.stderr)
+        sys.exit(2)
+    return _load(path)
 
 
 def _norms(tree: dict, names) -> np.ndarray:
@@ -72,6 +114,8 @@ def stream_numbers(s: dict, ref: dict, params0: dict) -> dict:
         out["grad1_gap"] = float(np.max(
             leaf_gaps(s["grad1"], ref["grad1"], names)))
     if s.get("params") is not None:
+        if s.get("start") is not None:
+            params0 = s["start"]["params"]
         dp = {k: s["params"][k] - params0[k] for k in names}
         dr = {k: ref["params"][k] - params0[k] for k in names}
         gaps = leaf_gaps(dp, dr, names)
@@ -85,82 +129,111 @@ def _score(nums: dict, limits: dict) -> float:
 
 
 @functools.lru_cache(maxsize=16)
-def _compiled(model_json: str, windows: int, matmul: str, fault: tuple):
+def _compiled(cell: str, model_json: str, windows: int, matmul: str,
+              fault: tuple):
     """The jitted reference, vmapped over streams (one per set of settings,
     so near-tie reruns reuse its compile)."""
     import jax
 
-    from bench.reference import make_reference
-    run = make_reference(json.loads(model_json), windows, matmul=matmul,
-                         **dict(fault))
-    return jax.jit(jax.vmap(run, in_axes=(None, None, 0, 0, 0)))
+    ref = reference_module(cell)
+    run = ref.make_reference(json.loads(model_json), windows, matmul=matmul,
+                             **dict(fault))
+    return jax.jit(jax.vmap(run, in_axes=(None, 0)))
 
 
-def run_reference(record: dict, flips=None, matmul: str = "highest",
+def _as(x, dtype):
+    """Floating arrays in `dtype`, integers as int32, booleans as they
+    are."""
+    a = np.asarray(x)
+    if np.issubdtype(a.dtype, np.floating):
+        return a.astype(dtype)
+    if np.issubdtype(a.dtype, np.integer):
+        return a.astype(np.int32)
+    return a
+
+
+def run_reference(part: dict, alts=None, matmul: str = "highest",
                   dtype=np.float64, device=None, **fault):
-    """The reference over every stream of the record at once: a list of
-    {loss, grad1, params, v} host dicts, one per stream (or per row of
-    `flips`, which then pairs with `streams` index list)."""
+    """The reference over every stream of a part at once: a list of host
+    dicts, one per stream, floating arrays in float64 (with `alts`, one
+    `alt` input per stream)."""
     import jax
     import jax.numpy as jnp
 
     device = device if device is not None else jax.devices("cpu")[0]
-    streams = record["streams"]
-    W = int(record["windows"])
-    xs = np.stack([s["xs"] for s in streams]).astype(dtype)
-    ys = np.stack([s["ys"] for s in streams]).astype(np.int32)
-    n = record["model"]["n_hidden"]
-    if flips is None:
-        flips = np.zeros(xs.shape[:3] + (n,), bool)
+    model = part["model"]
+    ref = reference_module(model["cell"])
+    streams = []
+    for i, s in enumerate(part["streams"]):
+        st = {k: s[k] for k in ref.INPUTS}
+        if s.get("start") is not None:
+            st["start"] = s["start"]
+        if alts is not None:
+            st["alt"] = alts[i]
+        streams.append(st)
+    stacked = jax.tree.map(lambda *xs: _as(np.stack(xs), dtype), *streams)
+    shared = {"params0": part["params0"], "masks": part["masks"]}
+    shared = jax.tree.map(lambda x: _as(x, dtype), shared)
     with jax.enable_x64(dtype == np.float64), jax.default_device(device):
-        run = _compiled(json.dumps(record["model"], sort_keys=True), W,
-                        matmul, tuple(sorted(fault.items())))
-        p0 = {k: jnp.asarray(v, dtype) for k, v in record["params0"].items()}
-        mk = {k: jnp.asarray(v, dtype) for k, v in record["masks"].items()}
-        out = jax.device_get(run(p0, mk, jnp.asarray(xs), jnp.asarray(ys),
-                                 jnp.asarray(flips)))
-    res = []
-    for i in range(len(streams)):
-        res.append({"loss": np.asarray(out["loss"][i], np.float64),
-                    "grad1": {k: np.asarray(v[i], np.float64)
-                              for k, v in out["grad1"].items()},
-                    "params": {k: np.asarray(v[i], np.float64)
-                               for k, v in out["params"].items()},
-                    "v": np.asarray(out["v"][i])})
-    return res
+        run = _compiled(model["cell"], json.dumps(model, sort_keys=True),
+                        int(part["windows"]), matmul,
+                        tuple(sorted(fault.items())))
+        out = jax.device_get(run(jax.tree.map(jnp.asarray, shared),
+                                 jax.tree.map(jnp.asarray, stacked)))
+    return [jax.tree.map(lambda x: _as(x[i], np.float64), out)
+            for i in range(len(streams))]
 
 
-def check(record: dict, limits: dict) -> tuple[dict, dict]:
-    """({number: {"value", "limit"}}, {"near_ties_tried",
-    "change_gap_worst_leaf"}) for the record; a number that no stream could
-    give reads inf."""
-    refs = run_reference(record)
-    streams = record["streams"]
-    best = [stream_numbers(s, r, record["params0"])
+def _prefixed(part: dict, name: str) -> str:
+    return f"{part['part']}.{name}" if part.get("part") else name
+
+
+def check_part(part: dict, limits: dict) -> tuple[dict, dict]:
+    """({name: value}, info) of one part; a number that no stream could
+    give is missing."""
+    refs = run_reference(part)
+    streams = part["streams"]
+    ref = reference_module(part["model"]["cell"])
+    own = {k: limits[_prefixed(part, k)] for k in NUMBERS
+           if _prefixed(part, k) in limits}
+    best = [stream_numbers(s, r, part["params0"])
             for s, r in zip(streams, refs)]
-    # near-tie events: rerun each tied stream with one event flipped
-    alt_streams, alt_flips, alt_of = [], [], []
+    # other exact outcomes (near-ties): judge each stream by the closest
+    alt_of, alt_in = [], []
     for i, r in enumerate(refs):
-        ties = np.argwhere(np.abs(r["v"]) < TIE_MARGIN)[:MAX_TIES]
-        for t in ties:
-            f = np.zeros(r["v"].shape, bool)
-            f[tuple(t)] = True
-            alt_streams.append(streams[i])
-            alt_flips.append(f)
+        for alt in getattr(ref, "alternatives", lambda _: [])(r):
             alt_of.append(i)
-    if alt_streams:
-        alts = run_reference(dict(record, streams=alt_streams),
-                             flips=np.stack(alt_flips))
+            alt_in.append(alt)
+    if alt_in:
+        alts = run_reference(dict(part, streams=[streams[i] for i in alt_of]),
+                             alts=alt_in)
         for i, r in zip(alt_of, alts):
-            nums = stream_numbers(streams[i], r, record["params0"])
-            if _score(nums, limits) < _score(best[i], limits):
+            nums = stream_numbers(streams[i], r, part["params0"])
+            if _score(nums, own) < _score(best[i], own):
                 best[i] = nums
-    out = {}
+    values = {}
     for name in NUMBERS:
         vals = [b[name] for b in best if name in b]
-        out[name] = {"value": max(vals) if vals else float("inf"),
-                     "limit": limits[name]}
+        if vals:
+            values[_prefixed(part, name)] = max(vals)
     worst = [b["change_gap_worst_leaf"] for b in best
              if "change_gap_worst_leaf" in b]
-    return out, {"near_ties_tried": len(alt_streams),
-                 "change_gap_worst_leaf": max(worst, default=None)}
+    return values, {_prefixed(part, "near_ties_tried"): len(alt_in),
+                    _prefixed(part, "change_gap_worst_leaf"):
+                    max(worst, default=None)}
+
+
+def check(record: list, limits: dict) -> tuple[dict, dict]:
+    """({name: {"value", "limit"}}, info) for every number that `limits`
+    names; a number that no stream of the record could give reads inf.  A
+    number that `limits` does not name is reported in `info`, not
+    compared."""
+    values, info = {}, {}
+    for part in record:
+        v, i = check_part(part, limits)
+        values.update(v)
+        info.update(i)
+    out = {name: {"value": values.get(name, float("inf")), "limit": lim}
+           for name, lim in limits.items()}
+    info.update({k: v for k, v in values.items() if k not in limits})
+    return out, info

@@ -38,19 +38,20 @@ def build_learner(config: dict):
     return learner, opt, params, masks, host, mask
 
 
-def make_telemetry(traced: bool, workdir: Path):
-    """The program's telemetry: inert for a timed run; for a traced run,
-    exporters on (so the update chunk packs its MetricPack) and spans on the
-    profiler's clock."""
+def make_telemetry(traced: bool, workdir: Path, exporters: bool = False):
+    """The program's telemetry: inert for a timed run unless `exporters`;
+    for a traced run, exporters on (so the update chunk packs its
+    MetricPack) and spans on the profiler's clock."""
     from repro.obs import Telemetry
     from repro.obs.events import EventLog
     from repro.obs.registry import Registry
     from repro.obs.trace import Tracer
 
-    events = EventLog(workdir / "events.jsonl") if traced else None
+    exporters = exporters or traced
+    events = EventLog(workdir / "events.jsonl") if exporters else None
     return Telemetry(Registry(), events,
                      Tracer(enabled=traced, jax_annotations=traced),
-                     workdir if traced else None, "bench", None)
+                     workdir if exporters else None, "bench", None)
 
 
 class Profile:

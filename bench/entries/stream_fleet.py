@@ -125,8 +125,8 @@ class Cell:
             w0 = max(first, s["joined"])
             losses += s["loss"][w0 - s["joined"]:]
         self.span_windows = (first, self.windows_done)
-        return C.window_record(stamps, t0, [n * self.k for n in live],
-                               losses)
+        return dict(C.window_record(stamps, t0, [n * self.k for n in live],
+                                    losses), program="fleet_chunk")
 
     def measure(self, seconds: float) -> dict:
         return self._run_windows(until=C.now() + seconds)
@@ -146,7 +146,7 @@ class Cell:
 
     # -- what the check compares -----------------------------------------------
 
-    def check_record(self) -> dict:
+    def check_record(self) -> list:
         n_t = self.n_check * self.k
         streams = []
         for sid in self.setup_sessions:
@@ -167,9 +167,9 @@ class Cell:
             for sid in pick:
                 streams.append(self._stream(sid, self.sessions[sid], n_t,
                                             None, None))
-        return {"model": self.model, "params0": self.params0,
-                "masks": self.mask, "windows": self.n_check,
-                "streams": streams}
+        return [{"part": "", "model": self.model, "params0": self.params0,
+                 "masks": self.mask, "windows": self.n_check,
+                 "streams": streams}]
 
     def _stream(self, sid, s, n_t, g1, pn):
         xs, ys = G.window_inputs(s["stream"], 0, n_t)

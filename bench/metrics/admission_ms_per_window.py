@@ -1,5 +1,6 @@
 """Host time per interval between windows spent admitting sessions
-(`fleet.admit` spans: slot claim, template copy, slot write)."""
+(`fleet.admit` spans: a template join claims a free slot and marks it for
+the next batched reset; an explicit or resumed state is written at once)."""
 from bench import stages
 
 

@@ -1,5 +1,6 @@
 """Host time per interval between windows spent retiring sessions
-(`fleet.retire` spans: slot reset to the template)."""
+(`fleet.retire` spans: the slot freed and marked for the next batched
+reset, on the host only)."""
 from bench import stages
 
 

@@ -5,8 +5,9 @@ configuration's `mask_seed` with density 1 - omega on every input and
 recurrent weight matrix (biases, thresholds and the readout stay dense, as
 in the paper's Sec. 5).  Weights come from the run's `--seed`, made on the
 device in one jitted call.  Both are flat dicts under the canonical leaf
-names of `bench/reference.py`; `to_flat` gives them the tree shape the
-program's single-layer learner takes.
+names of `bench/references/egru.py`; `to_flat` gives them the tree shape
+the program's single-layer learner takes, and `dense_influence` turns the
+program's influence carry into the reference's dense layout.
 """
 from __future__ import annotations
 
@@ -104,3 +105,50 @@ def mask_tree(model: dict, mask: dict) -> dict:
     tree = to_flat(full)
     del tree["out"]
     return tree
+
+
+def flat_columns(model: dict, mask: dict) -> np.ndarray:
+    """The live columns of the program's flat influence axis, ascending.
+
+    The flat axis of a one-layer EGRU (kind "gru") holds, per gate in
+    u, r, z order, one group of m = n_in + n + 1 columns per unit q: the
+    unit's input-weight column W[:, q], its recurrent-weight column
+    R[:, q] and its bias b[q]; then one column per threshold theta[q].  A
+    column lives where the masks keep its weight."""
+    n = model["n_hidden"]
+    parts = []
+    for g in GATES:
+        groups = np.concatenate([np.asarray(mask[f"{g}.W"]).T,
+                                 np.asarray(mask[f"{g}.R"]).T,
+                                 np.ones((n, 1))], axis=1)
+        parts.append(groups.reshape(-1))
+    parts.append(np.ones(n))
+    return np.nonzero(np.concatenate(parts) > 0)[0]
+
+
+def dense_influence(model: dict, mask: dict, vals, idx) -> dict:
+    """The program's column-compact, row-compact influence carry -> the
+    reference's dense layout {leaf: [B, n, *leaf shape]} of d a / d leaf.
+
+    vals [B, K, Pc_pad]: row s of example b is the influence of unit
+    idx[b, s] (-1: no unit; the rows of other units are zero), and compact
+    column c is the c-th live flat column (`flat_columns`; columns past
+    them are padding)."""
+    n, n_in = model["n_hidden"], model["n_in"]
+    m = n_in + n + 1
+    vals, idx = np.asarray(vals, np.float64), np.asarray(idx)
+    live = flat_columns(model, mask)
+    B = vals.shape[0]
+    flat = np.zeros((B, n, len(GATES) * n * m + n))
+    for b in range(B):
+        rows = idx[b] >= 0
+        flat[b, idx[b][rows][:, None], live[None, :]] = \
+            vals[b, rows][:, :live.size]
+    out = {}
+    for i, g in enumerate(GATES):
+        grp = flat[:, :, i * n * m:(i + 1) * n * m].reshape(B, n, n, m)
+        out[f"{g}.W"] = grp[..., :n_in].transpose(0, 1, 3, 2)
+        out[f"{g}.R"] = grp[..., n_in:n_in + n].transpose(0, 1, 3, 2)
+        out[f"{g}.b"] = grp[..., n_in + n]
+    out["theta"] = flat[:, :, len(GATES) * n * m:]
+    return out

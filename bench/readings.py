@@ -9,8 +9,10 @@ In one process, on the chip:
            numbers of sound runs, whose largest is a limit's lower reading;
   control  the plain reference put in the program's place, computed at the
            next precision below the configuration's (float32 matmuls as
-           three bf16 passes, XLA's `high`), on the cell's own inputs: its
-           numbers, whose smallest is a limit's upper reading;
+           three bf16 passes, XLA's `high`), on the inputs and from the
+           start states of a short program run of the cell (the program's
+           own outputs replaced): its numbers, whose smallest is a limit's
+           upper reading;
   half_batch  the same reference at full precision with half of every
            batch left out of the loss (the mean taken over the rest): a
            fault the limits must also catch;
@@ -34,45 +36,46 @@ ROOT = BENCH.parent
 PROGRAM_SECONDS = 2.0     # a short window: the check reads set-up's windows
 
 
-def inputs_record(r: dict, seed: int) -> dict:
-    """The check record of a cell's first streams, made from the seed alone
-    (no program run): weights, masks and each stream's inputs."""
-    from bench import model as M
-    from bench.traffic import generator as G
-    import jax
-    import numpy as np
+def program_record(r: dict, seed: int,
+                   seconds: float = PROGRAM_SECONDS) -> list:
+    """The check record of a short run of the cell's program: set-up, a
+    span of `seconds` and whatever the entry runs after it for the check."""
+    import shutil
+    import tempfile
 
-    mdl = r["config"]["model"]
-    mask = M.masks(mdl)
-    params0 = {k: np.asarray(v, np.float64) for k, v in
-               jax.device_get(M.params(mdl, seed, mask)).items()}
-    W = int(r["spec"]["check_windows"])
-    steps = W * int(mdl["update_every"])
-    traffic = G.Traffic(r["mix"], seed, mdl)
-    count = min(int(r["spec"]["slots"]),
-                int(r["spec"]["check_setup_sessions"]))
-    streams = [traffic.session(i)[1] for i in range(count)]
-    recs = []
-    for i, st in enumerate(streams):
-        xs, ys = G.window_inputs(st, 0, steps)
-        recs.append({"name": f"s{i}", "xs": xs, "ys": ys})
-    return {"model": mdl, "params0": params0, "masks": mask, "windows": W,
-            "streams": recs}
+    from bench import run as R
+    entry = R.load_module(r["entry"], "bench_entry")
+    work = Path(tempfile.mkdtemp(prefix="bench-"))
+    try:
+        cell = entry.Cell(r["config"], r["spec"], r["mix"], seed,
+                          traced=False, workdir=work)
+        cell.setup()
+        cell.measure(seconds)
+        record = cell.check_record()
+        cell.free()
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+    return record
 
 
-def stand_in(record: dict, **how) -> dict:
+def stand_in(record: list, **how) -> list:
     """The record with the reference's own outputs (run as `how` says, on
-    the default device in float32) in the program's place."""
+    the default device in float32) in the program's place: each loss, and
+    each first gradient and final parameters the program reported."""
     import jax
     import numpy as np
 
     from bench import check as CH
-    outs = CH.run_reference(record, dtype=np.float32,
-                            device=jax.devices()[0], **how)
-    streams = [dict(s, loss=list(o["loss"]), grad1=o["grad1"],
-                    params=o["params"])
-               for s, o in zip(record["streams"], outs)]
-    return dict(record, streams=streams)
+    parts = []
+    for part in record:
+        outs = CH.run_reference(part, dtype=np.float32,
+                                device=jax.devices()[0], **how)
+        streams = [dict(s, loss=list(o["loss"][:len(s["loss"])]),
+                        **{k: o[k] for k in ("grad1", "params")
+                           if s.get(k) is not None})
+                   for s, o in zip(part["streams"], outs)]
+        parts.append(dict(part, streams=streams))
+    return parts
 
 
 def main() -> int:
@@ -95,7 +98,7 @@ def main() -> int:
                           "checks": out["checks"], **out["check_info"],
                           "correct": out["correct"]}), flush=True)
     for seed in args.control_seeds:
-        rec = inputs_record(r, seed)
+        rec = program_record(r, seed)
         for name, how in (("control", {"matmul": "bf16x3"}),
                           ("half_batch", {"drop_half_batch": True}),
                           ("float32", {})):

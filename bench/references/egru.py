@@ -22,6 +22,21 @@ parameters follow the fixed-sparsity rule of the paper's Sec. 5.
 or "bf16x3" (each f32 operand split into a bf16 high and low part and three
 products summed in f32: XLA:TPU's `high` precision, written out so that it
 means the same on every backend).
+
+A run may start from a saved state instead of the zero state (`start`):
+the parameters, AdamW's m and v and its update count, the recurrent state
+a and the influence M = d a / d theta at the first step, in this module's
+dense layout ({leaf: [B, n, *leaf shape]}).  The gradient of a window is
+then two terms added together: reverse mode through the steps since the
+start, with a held fixed, and lambda^T M, where lambda = d L_window / d a
+at the start comes from the same reverse pass.  Both are exact; with M = 0
+and the zero state they are the run from the zero state.
+
+The Heaviside makes a unit's event depend on the sign of its
+pre-activation v.  Where the reference finds |v| under `TIE_MARGIN`,
+float32 rounding may decide the event either way, and both outcomes are
+exact results: `alternatives` gives the runs with one such event flipped
+(`alt`), and the check judges a stream against whichever it matches best.
 """
 from __future__ import annotations
 
@@ -29,8 +44,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 GATES = ("u", "r", "z")
+INPUTS = ("xs", "ys")   # a stream's input arrays, by their record names
+TIE_MARGIN = 1e-5     # |v| under this: float32 may take the event either way
+MAX_TIES = 4          # near-ties tried per stream
 
 
 def _mm(matmul: str):
@@ -66,31 +85,11 @@ def _heaviside_st(gamma: float, eps: float):
     return h
 
 
-def _xent(logits, labels):
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
-
-
-def make_reference(model: dict, windows: int, matmul: str = "highest",
-                   drop_half_batch: bool = False):
-    """Build `run(params0, masks, xs, ys, flips)` for one stream.
-
-    model: the configuration's cell and optimizer entries (n_hidden, gamma,
-    eps, update_every, lr, b1, b2, adam_eps).  xs [windows*k, B, n_in],
-    ys [windows*k, B] int32, flips [windows*k, B, n] bool (all False except
-    where a near-tie is resolved the other way).  Returns a dict: `loss`
-    [windows] (each window's loss before its update), `grad1` (the masked
-    gradient of the first window), `params` (after `windows` updates) and
-    `v` [windows*k, B, n] (the pre-activations along the trajectory).
-
-    drop_half_batch plants a fault for the benchmark's own checks: every
-    loss is the mean over the first half of the examples only."""
-    k = int(model["update_every"])
-    T = windows * k
+def make_cell(model: dict, matmul: str = "highest"):
+    """One EGRU step, `cell(p, a, x, flip) -> (a_new, v)`: the events a_new
+    and the pre-activations v, from the canonical leaves p."""
     mm = _mm(matmul)
     h = _heaviside_st(float(model["gamma"]), float(model["eps"]))
-    lr, b1, b2 = (float(model[x]) for x in ("lr", "b1", "b2"))
-    adam_eps = float(model["adam_eps"])
 
     def cell(p, a, x, flip):
         u = jax.nn.sigmoid(mm(x, p["u.W"]) + mm(a, p["u.R"]) + p["u.b"])
@@ -98,12 +97,39 @@ def make_reference(model: dict, windows: int, matmul: str = "highest",
         z = jnp.tanh(mm(x, p["z.W"]) + mm(r * a, p["z.R"]) + p["z.b"])
         v = u * z + (1.0 - u) * a - p["theta"]
         return h(v, flip), v
+    return cell
 
-    def history(delta, pstack, xs, ys, flips, w):
+
+def make_reference(model: dict, windows: int, matmul: str = "highest",
+                   drop_half_batch: bool = False):
+    """Build `run(shared, stream)` for one stream.
+
+    model: the configuration's cell and optimizer entries (n_hidden, gamma,
+    eps, update_every, lr, b1, b2, adam_eps).  shared: {"params0", "masks"}
+    (canonical leaves).  stream: {"xs" [windows*k, B, n_in], "ys"
+    [windows*k, B] int32}, and optionally "start" (see the module
+    docstring: {"params", "m", "v", "count", "state", "influence"}) and
+    "alt" [windows*k, B, n] bool (events flipped where a near-tie is
+    resolved the other way).  Returns a dict: `loss` [windows] (each
+    window's loss before its update), `grad1` (the masked gradient of the
+    first window), `params`, `m`, `v` and `state` after `windows` updates,
+    and `v_pre` [windows*k, B, n] (the pre-activations along the
+    trajectory).
+
+    drop_half_batch plants a fault for the benchmark's own checks: every
+    loss is the mean over the first half of the examples only."""
+    k = int(model["update_every"])
+    T = windows * k
+    mm = _mm(matmul)
+    cell = make_cell(model, matmul)
+    lr, b1, b2 = (float(model[x]) for x in ("lr", "b1", "b2"))
+    adam_eps = float(model["adam_eps"])
+
+    def history(delta, pstack, xs, ys, flips, w, a0):
         """Sum of window w's per-step losses (each / k), stepping the whole
-        history with the parameters of each step's window plus delta."""
-        B, n = xs.shape[1], pstack["u.R"].shape[-1]
-        a0 = jnp.zeros((B, n), xs.dtype)
+        history from a0 with the parameters of each step's window plus
+        delta."""
+        B = xs.shape[1]
         if drop_half_batch:
             keep = jnp.arange(B) < B // 2
         else:
@@ -119,28 +145,56 @@ def make_reference(model: dict, windows: int, matmul: str = "highest",
             step_loss = jnp.sum(nll * keep) / jnp.sum(keep) / k
             return a, (jnp.where(t // k == w, step_loss, 0.0), v)
 
-        _, (losses, vs) = jax.lax.scan(
+        a, (losses, vs) = jax.lax.scan(
             body, a0, (jnp.arange(T), xs, ys, flips))
-        return jnp.sum(losses), vs
+        return jnp.sum(losses), (vs, a)
 
     grad_fn = jax.value_and_grad(history, has_aux=True)
+    grad_fn_start = jax.value_and_grad(history, argnums=(0, 6),
+                                       has_aux=True)
 
-    def run(params0, masks, xs, ys, flips):
+    def run(shared, stream):
+        params0, masks = shared["params0"], shared["masks"]
+        xs, ys = stream["xs"], stream["ys"]
+        start = stream.get("start")
+        flips = stream.get("alt")
+        n = params0["u.R"].shape[-1]
+        if flips is None:
+            flips = jnp.zeros(xs.shape[:2] + (n,), bool)
         mask = {name: masks.get(name, jnp.ones_like(v))
                 for name, v in params0.items()}
         zeros = jax.tree.map(jnp.zeros_like, params0)
-        pstack = jax.tree.map(lambda v: jnp.stack([v] * windows), params0)
-        p, m, s = params0, zeros, zeros
+        if start is None:
+            p, m, s = params0, zeros, zeros
+            a0 = jnp.zeros((xs.shape[1], n), xs.dtype)
+        else:
+            p, m, s = start["params"], start["m"], start["v"]
+            a0 = start["state"]
+        pstack = jax.tree.map(lambda v: jnp.stack([v] * windows), p)
         losses, grad1, vs = [], None, None
         for w in range(windows):
             pstack = jax.tree.map(lambda st, v: st.at[w].set(v), pstack, p)
-            (loss, vs), g = grad_fn(zeros, pstack, xs, ys, flips, w)
+            if start is None:
+                # from the zero state: the arithmetic of the reference before
+                # starts existed, digit for digit
+                (loss, (vs, a)), g = grad_fn(zeros, pstack, xs, ys, flips,
+                                             w, a0)
+                c1 = 1.0 - b1 ** (w + 1)
+                c2 = 1.0 - b2 ** (w + 1)
+            else:
+                (loss, (vs, a)), (g, lam) = grad_fn_start(
+                    zeros, pstack, xs, ys, flips, w, a0)
+                # the influence of the history before the start
+                g = {name: g_ + (jnp.tensordot(lam, start["influence"][name],
+                                               axes=([0, 1], [0, 1]))
+                                 if name in start["influence"] else 0.0)
+                     for name, g_ in g.items()}
+                c1 = 1.0 - b1 ** (start["count"] + w + 1)
+                c2 = 1.0 - b2 ** (start["count"] + w + 1)
             g = jax.tree.map(jnp.multiply, g, mask)
             if grad1 is None:
                 grad1 = g
             losses.append(loss)
-            c1 = 1.0 - b1 ** (w + 1)
-            c2 = 1.0 - b2 ** (w + 1)
             m = jax.tree.map(lambda m_, g_: b1 * m_ + (1 - b1) * g_, m, g)
             s = jax.tree.map(lambda s_, g_: b2 * s_ + (1 - b2) * g_ * g_,
                              s, g)
@@ -149,6 +203,18 @@ def make_reference(model: dict, windows: int, matmul: str = "highest",
                                         / (jnp.sqrt(s_ / c2) + adam_eps))
                 * k_, p, m, s, mask)
         return {"loss": jnp.stack(losses), "grad1": grad1, "params": p,
-                "v": vs}
+                "m": m, "v": s, "state": a, "v_pre": vs}
 
     return run
+
+
+def alternatives(out: dict) -> list:
+    """The `alt` inputs of the near-tie reruns of one stream, from its
+    reference run `out` (host arrays): one event flipped each, at most
+    MAX_TIES of them."""
+    alts = []
+    for t in np.argwhere(np.abs(out["v_pre"]) < TIE_MARGIN)[:MAX_TIES]:
+        f = np.zeros(out["v_pre"].shape, bool)
+        f[tuple(t)] = True
+        alts.append(f)
+    return alts

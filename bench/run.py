@@ -7,18 +7,24 @@ cell is an entry of `BENCHMARK.json`'s `workloads`; everything about it is
 found by name:
 
   bench/workloads/<cell>.json    the entry that drives it and its settings
+                                 (a `learner` object there overrides the
+                                 configuration's learner settings)
   bench/configs/<config>.json    the model, learner and optimizer
   bench/traffic/<mix>.json       the traffic mix (`bench/traffic/generator.py`)
   bench/metrics/<metric>.py      one reader per metric, `read(ctx)`
   bench/entries/<entry>.py       how the program's entry point is driven
+  bench/references/<cell>.py     the plain reference of the configuration's
+                                 `model.cell` (`bench/check.py`)
 
 A run builds the cell from the seed, warms up its shapes by driving its
 first update windows (set-up), then measures whole update windows until
 `--seconds` have passed (`--trace 0`) or traces a few windows with the
-profiler (`--trace 1`).  It then reads the device's peak memory, frees the
-program's state, and checks what the timed path produced against the plain
-reference (`bench/check.py`).  The last line of standard output is one JSON
-object: correct, attempted, failed, metrics, device[, breakdown], checks.
+profiler (`--trace 1`).  It then reads the device's peak memory, takes
+what the check compares (an entry may run more untimed windows for it),
+frees the program's state, and checks what the timed path produced against
+the plain reference (`bench/check.py`).  The last line of standard output
+is one JSON object: correct, attempted, failed, metrics, device[,
+breakdown], checks.
 
 Exits 2 where the program's sources are missing, 3 where JAX finds no TPU or
 fewer chips than the cell asks for; nothing is printed to standard output
@@ -80,6 +86,9 @@ def resolve(workload: str) -> dict:
     spec = load_json(BENCH / "workloads" / f"{check_name(workload)}.json")
     mix = load_json(BENCH / "traffic" / f"{check_name(cell['traffic'])}.json")
     config = load_json(ROOT / cfg_entry["file"])
+    config["learner"] = dict(config["learner"], **spec.get("learner", {}))
+    from bench import check as CH
+    CH.reference_module(check_name(config["model"]["cell"]))
 
     def wanted(m):
         return "workloads" not in m or workload in m["workloads"]
@@ -179,9 +188,10 @@ def run_cell(r: dict, seed: int, seconds: float, trace: bool, devs) -> dict:
             breakdown = TR.breakdown(ctx["trace"])
         metrics = read_metrics(
             r["metrics"]["per_layer" if trace else "end_to_end"], ctx)
+        record = cell.check_record()
         cell.free()
         gc.collect()
-        checks, info = CH.check(cell.check_record(), r["spec"]["limits"])
+        checks, info = CH.check(record, r["spec"]["limits"])
         log += [f"{k}: {v!r}" for k, v in info.items()]
     finally:
         shutil.rmtree(work, ignore_errors=True)

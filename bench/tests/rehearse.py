@@ -9,7 +9,10 @@ from bench import run as R
 TINY = {"n16-fleet1024": {"model": {"n_hidden": 8, "batch": 4,
                                   "update_every": 2},
                         "learner": {}, "spec": {"slots": 3,
-                                                "check_span_sessions": 3}}}
+                                                "check_span_sessions": 3}},
+        "n16-stream": {"model": {"n_hidden": 8, "batch": 4,
+                                 "update_every": 2},
+                       "learner": {}, "spec": {"trace_windows": 4}}}
 
 
 def resolve_tiny(workload: str) -> dict:

@@ -60,8 +60,13 @@ def test_cell_resolves_every_file_by_name(workload):
             reader = R.load_module(R.BENCH / "metrics" / f"{m['name']}.py",
                                    "reader")
             assert callable(reader.read)
-    assert set(r["spec"]["limits"]) == {"loss_gap", "grad1_gap",
-                                        "change_gap_median"}
+    from bench import check as CH
+    assert r["spec"]["limits"]
+    for name in r["spec"]["limits"]:
+        assert name.rsplit(".", 1)[-1] in CH.NUMBERS, name
+    assert {"loss_gap", "grad1_gap", "change_gap_median"} \
+        <= set(r["spec"]["limits"])
+    assert CH.reference_path(r["config"]["model"]["cell"]).is_file()
 
 
 def test_peaks_table():

@@ -6,7 +6,7 @@ import pytest
 
 from bench.tests.rehearse import run_tiny
 
-CELLS = ("n16-fleet1024",)
+CELLS = ("n16-fleet1024", "n16-stream")
 
 
 @pytest.mark.parametrize("workload", CELLS)

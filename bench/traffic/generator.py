@@ -5,15 +5,16 @@ A mix is parameters only:
 
   kind        "sessions": a queue of sessions, each its own stream, that
               join free slots of a fleet and leave after some update
-              windows (a saturated closed-loop drain).
+              windows (a saturated closed-loop drain); "stream": one
+              endless stream (`session(0)`), that never leaves.
   inputs      "spiral": the paper's 2-D spirals (Sec. 6), one sequence of
               `seq_len` steps per example, label = orientation.
-  session_windows_mean  after every window a fixed share 1 / mean of the
-                       live sessions leaves (the running total rounded
-                       down, so every seed has the same count in every
-                       window), the leavers picked by the seed: a session's
-                       length is then geometric with that mean, and every
-                       seed offers the same work per window.
+  session_windows_mean  ("sessions") after every window a fixed share
+                       1 / mean of the live sessions leaves (the running
+                       total rounded down, so every seed has the same count
+                       in every window), the leavers picked by the seed: a
+                       session's length is then geometric with that mean,
+                       and every seed offers the same work per window.
   spirals, seq_len, noise  size of the spiral set, steps per sequence,
                        observation noise.
 
@@ -92,11 +93,11 @@ class Traffic:
     def __init__(self, mix: dict, seed: int, model: dict):
         self.mix, self.seed = mix, seed
         self.B = model["batch"]
-        if mix["kind"] != "sessions":
+        if mix["kind"] not in ("sessions", "stream"):
             raise ValueError(f"unknown traffic kind {mix['kind']!r}")
         if mix["inputs"] != "spiral":
             raise ValueError(f"unknown inputs {mix['inputs']!r}")
-        self.mean = float(mix["session_windows_mean"])
+        self.mean = float(mix.get("session_windows_mean", math.inf))
         self.xs_all, self.ys_all = spiral_set(
             seed, int(mix["spirals"]), int(mix["seq_len"]),
             float(mix["noise"]))
