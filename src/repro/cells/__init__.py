@@ -6,6 +6,8 @@ recurrent architecture, so `repro.core.learner`'s engines are cell-agnostic:
     cell.name            short id ("egru" | "rglru" | "snn" | "diag")
     cell.jac_kind        "dense"    -> partials yields J-hat [B, n, n]
                          "diagonal" -> partials yields the diagonal [B, n]
+                         "head_diagonal" -> one decay per head of a
+                                    [B, H, P, N] state (no partials)
     cell.cfg             the config dataclass the cell was built from
     cell.init_params(key)            full parameter tree (incl. readout)
     cell.rec_params(params)          the recurrent subset w
@@ -20,7 +22,9 @@ What `mbar` means depends on jac_kind: for dense cells it is the EGRU
 per-gate Mbar-group dict the flat influence layout consumes; for diagonal
 cells it is a pytree of per-parameter trace increments (trailing axis n),
 and cells additionally expose `init_traces(batch)` so `engine="diag_exact"`
-can carry exact O(n·p) eligibility traces.  The SNN cell instead exposes
+can carry exact O(n·p) eligibility traces.  Cells of both diagonal kinds
+give the two halves of a `diag_exact` step, `exact_terms` and
+`trace_update` (`rglru.DiagonalExact` for jac_kind "diagonal").  The SNN cell instead exposes
 `eprop_step` for the approximate `engine="eprop"` recursion (see
 repro.cells.snn).
 
@@ -34,6 +38,7 @@ from typing import Any, Protocol, runtime_checkable
 import jax
 
 from repro.cells.egru import EGRUCell
+from repro.cells.mamba2 import GraniteHybridConfig, Mamba2PeriodCell
 from repro.cells.rglru import DiagCell, RGLRUCell, RGLRUCellConfig
 from repro.cells.snn import SNNCell, SNNConfig
 
@@ -68,6 +73,7 @@ CELLS = {
     "rglru": RGLRUCell,
     "snn": SNNCell,
     "diag": DiagCell,
+    "mamba2": Mamba2PeriodCell,
 }
 
 
@@ -92,6 +98,8 @@ def resolve_cell(cfg: Any) -> Cell:
         return SNNCell(cfg)
     if isinstance(cfg, DiagCellConfig):
         return DiagCell(cfg)
+    if isinstance(cfg, GraniteHybridConfig):
+        return Mamba2PeriodCell(cfg)
     raise ValueError(
         f"no cell registered for config type {type(cfg).__name__!r}; "
         f"known cells: {tuple(CELLS)}")

@@ -150,7 +150,47 @@ def bptt_loss_and_grads(cfg: RGLRUCellConfig, params, xs, labels):
     return jax.value_and_grad(loss_fn)(params)
 
 
-class RGLRUCell:
+class DiagonalExact:
+    """The two halves of a `DiagExactLearner` step for a jac_kind="diagonal"
+    cell (J_t = diag(a_t), a_t [B, n]): `exact_terms` gives the partials
+    (masked increments) and the readout's reverse mode, lam = dL_t/dh_t,
+    with no immediate term on the recurrent parameters; `trace_update`
+    applies e_t[w] = a_t * e_{t-1}[w] + mbar_t[w] and contracts lam."""
+
+    def exact_terms(self, params, frozen, h, x_t, y_t, tt, masks=None):
+        """-> (h', a_t, mbar, lam, None, g_out, loss, logits, stats)."""
+        w = self.rec_params(params)
+        h_new, hp, adiag, mbar = self.partials(w, h, x_t)
+        if masks is not None:
+            mbar = jax.tree.map(lambda m, mk: m * mk, mbar, masks)
+
+        def inst_loss(po, hi):
+            logits = self.readout({"out": po}, hi)
+            lab = jnp.maximum(y_t, 0)
+            ls = jax.nn.log_softmax(logits, -1)
+            return -jnp.mean(jnp.take_along_axis(ls, lab[:, None], 1)) / tt
+
+        lt, (gout_t, cbar) = jax.value_and_grad(inst_loss, argnums=(0, 1))(
+            params["out"], h_new)
+        return (h_new, adiag, mbar, cbar, None, gout_t, lt,
+                self.readout(params, h_new), {})
+
+    @staticmethod
+    def trace_update(tr, adiag, mbar, cbar):
+        """a_t [B, n] broadcast over each leaf [B, ..., n]; -> (traces',
+        the step's gradient cbar^T e_t)."""
+        def decay(leaf):
+            shape = ((adiag.shape[0],) + (1,) * (leaf.ndim - 2)
+                     + (adiag.shape[-1],))
+            return jnp.reshape(adiag, shape)
+
+        tr_new = jax.tree.map(lambda t, m: decay(t) * t + m, tr, mbar)
+        gw_t = jax.tree.map(
+            lambda e: jnp.einsum("bk,b...k->...k", cbar, e), tr_new)
+        return tr_new, gw_t
+
+
+class RGLRUCell(DiagonalExact):
     """RG-LRU behind the pluggable cell protocol: jac_kind="diagonal", so
     the third `partials` output is the diagonal a_t [B, n], not a [B, n, n]
     Jacobian, and mbar is the per-parameter trace increment tree."""
@@ -186,7 +226,7 @@ class RGLRUCell:
         return h != 0.0
 
 
-class DiagCell:
+class DiagCell(DiagonalExact):
     """The original toy diagonal cell (`repro.core.diag_rtrl`, no input
     gate) behind the same protocol — `engine="diag"` dispatches through this
     adapter; carry structure and trace math are the historical ones."""

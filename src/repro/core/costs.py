@@ -165,6 +165,25 @@ def diag_influence_flops(n: int, p: int, omega: float = 0.0) -> float:
     return 2.0 * (1.0 - omega) * p
 
 
+def head_diag_trace_cost(B: int, heads: int, P: int, N: int, D: int,
+                         K: int, dtype_bytes: int = 4) -> dict:
+    """One step of the HEAD-DIAGONAL exact-RTRL trace update
+    (`repro.cells.mamba2`, `kernels/ssm_trace.py`): `heads` local heads of
+    a [P, N] state each, three traces [B, heads, P, N, D] (the x, B and dt
+    rows of in_proj, D their input width) and small ones over the conv taps
+    and biases of x and B (2K + 2 per state entry), A_log and dt_bias.
+    Each trace element takes T' = d T + f u and lam T' into the gradient:
+    5 ops for a big trace, 4 for a small one (its increment given whole).
+    One read and one write of every trace is the least traffic; the
+    increments arrive as rank-1 factors, never trace-sized.  -> {"flops",
+    "bytes", "trace_floats"} per step."""
+    state = B * heads * P * N
+    big, small = 3 * state * D, state * (2 * K + 4)
+    return {"flops": 5.0 * big + 4.0 * small,
+            "bytes": float(dtype_bytes) * (2 * big + 3 * small),
+            "trace_floats": big + small}
+
+
 def eprop_trace_bytes(B: int, n: int, n_in: int, dtype_bytes: int = 4,
                       adaptive: bool = True) -> int:
     """e-prop trace memory (repro.cells.snn): rank-1 membrane traces

@@ -85,9 +85,11 @@ class LearnerSpec:
                  stacked           StackedEGRUConfig (or EGRUConfig + layers)
                  scaled            scaled_rtrl.ScaledRTRLConfig
                  diag              diag_rtrl.DiagCellConfig
-                 diag_exact        any jac_kind="diagonal" cell config
+                 diag_exact        any jac_kind="diagonal" or
+                                   "head_diagonal" cell config
                                    (cells.rglru.RGLRUCellConfig,
-                                   DiagCellConfig)
+                                   DiagCellConfig,
+                                   cells.mamba2.GraniteHybridConfig)
                  eprop             cells.snn.SNNConfig
     backend    sparse/stacked influence execution:
                dense | pallas | compact | compact_fused
@@ -1128,30 +1130,48 @@ class ScaledLearner(_LearnerBase):
 # ---------------------------------------------------------------------------
 
 class DiagExactLearner(_LearnerBase):
-    """Exact eligibility-trace RTRL for ANY jac_kind='diagonal' zoo cell
-    (RG-LRU via `repro.cells.rglru`, the diag_rtrl toy cell, the RWKV decay
-    family): J_t = diag(a_t) factors the influence matrix into independent
-    per-parameter traces
+    """Exact eligibility-trace RTRL for zoo cells whose state Jacobian is
+    diagonal, per unit or per head:
 
-        e_t[w] = a_t * e_{t-1}[w] + mbar_t[w]
+      jac_kind "diagonal" (RG-LRU via `repro.cells.rglru`, the diag_rtrl toy
+        cell, the RWKV decay family): J_t = diag(a_t), a_t [B, n], factors
+        the influence matrix into independent per-parameter traces
 
-    so one step costs O(n·p) FLOPs and O(p) trace memory — no [B, K, P]
-    influence buffer and no n² Jacobian factor.  `engine="diag_exact"` is
-    the cell-agnostic spelling; `engine="diag"` keeps the historical name
-    (same carry layout for DiagCellConfig specs).  With parameter masks the
+            e_t[w] = a_t * e_{t-1}[w] + mbar_t[w]
+
+        so one step costs O(n·p) FLOPs and O(p) trace memory — no [B, K, P]
+        influence buffer and no n² Jacobian factor.
+      jac_kind "head_diagonal" (the Mamba-2 mixer of `repro.cells.mamba2`):
+        a state [B, H, P, N] whose decay is one scalar per head, traces
+        [B, H, P, N, ...] with rank-1 increments; the cell gives the step's
+        terms and the one-pass trace update, and its learned leaves also
+        reach the loss without the state (immediate terms, added to the
+        traces' gradient).  The layers around the learned one run forward
+        only: their weights (`init(..., frozen=...)`) ride in the carry.
+
+    Either way a step is the cell's two halves: `cell.exact_terms` (new
+    state, decay, increments, lam = dL_t/dh_t, immediate gradients, loss,
+    logits, stats; `cells.rglru.DiagonalExact` for the diagonal kind), then
+    `cell.trace_update`, which also contracts lam against the new traces.
+    `engine="diag_exact"` is the cell-agnostic spelling; `engine="diag"`
+    keeps the historical name (same carry layout for DiagCellConfig
+    specs).  With parameter masks the
     trace increments of dead parameters are zeroed every step, so their
     traces and gradients stay exactly 0."""
+
+    KINDS = ("diagonal", "head_diagonal")
 
     def __init__(self, spec: LearnerSpec):
         self.spec = spec
         self.cfg = spec.cfg
         self.cell = resolve_cell(spec.cfg)
-        if self.cell.jac_kind != "diagonal":
+        if self.cell.jac_kind not in self.KINDS:
             raise ValueError(
                 f"engine='diag_exact' needs a diagonal-Jacobian cell; "
                 f"{self.cell.name!r} has jac_kind={self.cell.jac_kind!r}")
 
-    def init(self, params, masks, batch, t_total: float = 1.0):
+    def init(self, params, masks, batch, t_total: float = 1.0,
+             frozen=None):
         x0, _ = batch
         B = x0.shape[0]
         self._freeze_static(masks=masks)
@@ -1162,36 +1182,19 @@ class DiagExactLearner(_LearnerBase):
         carry["gw"] = jax.tree.map(jnp.zeros_like,
                                    self.cell.rec_params(params))
         carry["gout"] = jax.tree.map(jnp.zeros_like, params["out"])
+        if frozen is not None:
+            carry["frozen"] = frozen
         return carry
 
     @exact_matmuls
     def step(self, carry, x_t, y_t):
         params = carry["params"]
-        w = self.cell.rec_params(params)
-        tt = carry["t_total"]
-        h_new, hp, adiag, mbar = self.cell.partials(w, carry["h"], x_t)
-        if self.masks is not None:
-            mbar = jax.tree.map(lambda m, mk: m * mk, mbar, self.masks)
-
-        def decay(leaf):
-            # broadcast a_t [B, n] over a leaf [B, ..., n]
-            shape = ((adiag.shape[0],) + (1,) * (leaf.ndim - 2)
-                     + (adiag.shape[-1],))
-            return jnp.reshape(adiag, shape)
-
-        tr_new = jax.tree.map(lambda t, m: decay(t) * t + m,
-                              carry["tr"], mbar)
-
-        def inst_loss(po, hi):
-            logits = self.cell.readout({"out": po}, hi)
-            lab = jnp.maximum(y_t, 0)
-            ls = jax.nn.log_softmax(logits, -1)
-            return -jnp.mean(jnp.take_along_axis(ls, lab[:, None], 1)) / tt
-
-        lt, (gout_t, cbar) = jax.value_and_grad(inst_loss, argnums=(0, 1))(
-            params["out"], h_new)
-        gw_t = jax.tree.map(
-            lambda e: jnp.einsum("bk,b...k->...k", cbar, e), tr_new)
+        h_new, decay, mbar, lam, g_imm, gout_t, lt, logits, stats = (
+            self.cell.exact_terms(params, carry.get("frozen"), carry["h"],
+                                  x_t, y_t, carry["t_total"], self.masks))
+        tr_new, gw_t = self.cell.trace_update(carry["tr"], decay, mbar, lam)
+        if g_imm is not None:
+            gw_t = jax.tree.map(jnp.add, gw_t, g_imm)
         new = dict(carry)
         new["h"], new["tr"] = h_new, tr_new
         new["gw"] = jax.tree.map(jnp.add, carry["gw"], gw_t)
@@ -1201,8 +1204,7 @@ class DiagExactLearner(_LearnerBase):
         if self.spec.per_step_grads:
             step_grads = dict(gw_t)
             step_grads["out"] = gout_t
-        return new, StepOut(lt, self.cell.readout(params, h_new), {},
-                            step_grads)
+        return new, StepOut(lt, logits, stats, step_grads)
 
     def grads(self, carry):
         grads = dict(carry["gw"])

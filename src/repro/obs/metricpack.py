@@ -65,6 +65,15 @@ def _stat_mean(key):
     return fn
 
 
+def _stat_sum(key):
+    def fn(env):
+        stats = env.get("stats") or {}
+        if key not in stats:
+            return _scalar(_NAN)
+        return _scalar(jnp.sum(jnp.asarray(stats[key], jnp.float32)))
+    return fn
+
+
 def _f_loss(env):
     return _scalar(env["loss"])
 
@@ -157,6 +166,8 @@ DEFAULT_FIELDS = (
     ("kb_max", _f_kb("max")),
     ("clip_factor", _f_env("clip_factor", 1.0)),  # guard norm-clip scale (1 = untouched)
     ("health", _f_env("health", 0.0)),       # guard finiteness bitmask (0 = healthy)
+    ("resets", _stat_sum("resets")),         # document starts in the window (token streams)
+    ("tokens", _stat_sum("tokens")),         # tokens the window consumed (token streams)
 )
 
 

@@ -35,10 +35,11 @@ import time
 MAX_SPANS = 200_000
 
 # the named scopes of the update chunk (`core/sparse_rtrl.py`,
-# `core/learner.py`, `runtime/online.py`, `runtime/fleet.py`,
-# `obs/metricpack.py`)
+# `core/learner.py`, `cells/mamba2.py`, `runtime/online.py`,
+# `runtime/fleet.py`, `obs/metricpack.py`)
 STAGES = ("partials", "j_tile_gather", "mbar_rows", "influence_update",
-          "grad_readout", "optimizer", "telemetry")
+          "grad_readout", "optimizer", "telemetry", "ssm_trace_update",
+          "frozen_blocks", "ssm_readout")
 
 _NULL = contextlib.nullcontext()
 _current: "Tracer | None" = None

@@ -125,12 +125,24 @@ class OnlineTrainer:
     snapshot ring, rollback-and-replay under an escalating degradation
     policy.  fault_plan (`guard.FaultPlan`): deterministic fault
     injection for tests/CI.  shardings: optional leaf-complete tree of
-    target shardings over `_ckpt_tree()` for elastic re-mesh resume."""
+    target shardings over `_ckpt_tree()` for elastic re-mesh resume.
+
+    frozen: the weights of blocks that run forward only (a learner whose
+    cell has them, `repro.cells.mamba2`); they ride in the carry, and the
+    trainer owns them from here on.  The unguarded chunk donates the carry
+    and the optimizer state, so the influence state is updated in place
+    and never held twice; the trainer copies any carry leaf that is one of
+    the caller's params or masks first, so those stay the caller's.
+    Under an enabled tracer the chunk's stage map is noted as
+    `"online_chunk"` after its first dispatch (`Tracer.note_program`)."""
+
+    PROGRAM = "online_chunk"
 
     def __init__(self, cfg: OnlineTrainerConfig, learner, opt, params: Tree,
                  masks: Tree | None, stream: Callable[[int], tuple],
                  rewire_schedule=None, guard=None, fault_plan=None,
-                 shardings: Tree | None = None, telemetry=None):
+                 shardings: Tree | None = None, telemetry=None,
+                 frozen: Tree | None = None):
         self.cfg = cfg
         self.learner = learner
         self.opt = opt
@@ -149,9 +161,12 @@ class OnlineTrainer:
         self.shardings = shardings      # leaf-complete over _ckpt_tree()
         x0, y0 = stream(0)
         tt = cfg.t_total if cfg.t_total is not None else float(cfg.update_every)
-        self.carry = learner.init(params, masks,
-                                  (jnp.asarray(x0), jnp.asarray(y0)),
-                                  t_total=tt)
+        extra = {} if frozen is None else {"frozen": frozen}
+        carry = learner.init(params, masks,
+                             (jnp.asarray(x0), jnp.asarray(y0)),
+                             t_total=tt, **extra)
+        self.carry = _owned(carry, (params, masks))
+        self._noted = False
         self.opt_state = jax.jit(opt.init)(params)
         if rewire_schedule is not None:
             # fail at construction, not at the first event hours into a run
@@ -189,7 +204,8 @@ class OnlineTrainer:
         pack = self._pack
         self._chunk = jax.jit(
             lambda carry, opt_state, xs, ys, upd: online_update_chunk(
-                learner, opt, carry, opt_state, xs, ys, upd, pack=pack))
+                learner, opt, carry, opt_state, xs, ys, upd, pack=pack),
+            donate_argnums=(0, 1))
         self.guard = None
         if guard is not None:
             # lazy import: guard.py imports this module at its top level
@@ -236,7 +252,7 @@ class OnlineTrainer:
         tree, upd = self.ckpt.restore(self._ckpt_tree(), self.shardings)
         if tree is None:
             return False
-        self.carry, self.opt_state = tree["carry"], tree["opt"]
+        self.carry, self.opt_state = _owned((tree["carry"], tree["opt"]))
         self.step = int(tree["pos"])
         self.update = upd
         self.rewire_events = int(tree["rewire_events"])
@@ -271,6 +287,8 @@ class OnlineTrainer:
             if isinstance(self.opt_state, dict) and "mask" in self.opt_state:
                 self.opt_state = set_opt_mask(
                     self.opt_state, self.learner.opt_mask_of(self.carry))
+            # the optimizer's mask may be the carry's own arrays
+            self.carry, self.opt_state = _owned((self.carry, self.opt_state))
         self.rewire_events = ev + 1
         fp = self.carry_nbytes()
         ms = round((time.perf_counter() - t0) * 1e3, 2)
@@ -396,8 +414,21 @@ class OnlineTrainer:
             return True, {}, {"guard_action": action}
         xs, ys = self._gather(start, k)
         if g is None:
-            self.carry, self.opt_state, m = self._chunk(
-                self.carry, self.opt_state, xs, ys, jnp.int32(self.update))
+            args = (self.carry, self.opt_state, xs, ys,
+                    jnp.int32(self.update))
+            shapes = None
+            if not self._noted and self.obs.tracer.enabled:
+                shapes = jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                                   sharding=x.sharding),
+                    args)
+            self.carry, self.opt_state, m = self._chunk(*args)
+            if shapes is not None:
+                # after the first dispatch: the compile is served from the
+                # caches
+                self.obs.tracer.note_program(self.PROGRAM, self._chunk,
+                                             *shapes)
+                self._noted = True
             if self._pack is not None:
                 # THE window readback: one packed vector, blocks like the
                 # loss fetch it replaces
@@ -519,6 +550,20 @@ class OnlineTrainer:
         return out
 
 
+def _owned(tree: Tree, theirs: Tree = ()) -> Tree:
+    """`tree` with a copy of every leaf that is one of `theirs`' leaves or
+    repeats an earlier leaf: a donated tree may hold each buffer once, and
+    none that its caller still holds."""
+    seen = {id(x) for x in jax.tree.leaves(theirs)}
+
+    def own(x):
+        if id(x) in seen:
+            return jnp.copy(x)
+        seen.add(id(x))
+        return x
+    return jax.tree.map(own, tree)
+
+
 def _legacy_metrics(pk: dict) -> dict:
     """Unpacked MetricPack dict -> the chunk-metrics keys the log records
     always carried (loss / alpha / beta / overflow).  NaN fields are the
@@ -535,6 +580,7 @@ def _legacy_metrics(pk: dict) -> dict:
 
 def carry_nbytes(carry: Tree) -> int:
     """Total bytes held by the learner carry — the O(1)-in-stream-length
-    memory claim, as a number callers can assert on and logs can report."""
-    return int(sum(np.asarray(jax.device_get(x)).nbytes
+    memory claim, as a number callers can assert on and logs can report.
+    From the leaves' shapes and dtypes: nothing is read back."""
+    return int(sum(x.nbytes if hasattr(x, "nbytes") else np.asarray(x).nbytes
                    for x in jax.tree.leaves(carry)))

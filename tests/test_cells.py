@@ -35,17 +35,23 @@ def _tree_allclose(g1, g2, atol=1e-7, rtol=1e-4):
 def test_every_cell_satisfies_protocol():
     """Every registry entry satisfies the structural Cell protocol and
     resolve_cell maps its config type back to it."""
+    from repro.cells.mamba2 import GraniteHybridConfig
     from repro.core.diag_rtrl import DiagCellConfig
     cfgs = {"egru": EGRUConfig(n_hidden=8, n_in=3, n_out=2, kind="gru"),
             "rglru": R.RGLRUCellConfig(n=8, n_in=3, n_out=2),
             "snn": S.SNNConfig(n=8, n_in=3, n_out=2),
-            "diag": DiagCellConfig(n=8, n_in=3, n_out=2)}
+            "diag": DiagCellConfig(n=8, n_in=3, n_out=2),
+            "mamba2": GraniteHybridConfig(
+                hidden_size=16, intermediate_size=32, vocab_size=32,
+                layer_types=("attention", "mamba"), mamba_n_heads=2,
+                mamba_d_head=4, mamba_d_state=8, num_attention_heads=2,
+                num_key_value_heads=1, local_heads=(0, 1), kv_slots=8)}
     assert set(CELLS) == set(cfgs)
     for name, cfg in cfgs.items():
         cell = make_cell(name, cfg)
         assert isinstance(cell, Cell), name
         assert cell.name == name
-        assert cell.jac_kind in ("dense", "diagonal"), name
+        assert cell.jac_kind in ("dense", "diagonal", "head_diagonal"), name
         assert resolve_cell(cfg).__class__ is cell.__class__, name
         params = cell.init_params(jax.random.key(0))
         w = cell.rec_params(params)
@@ -129,7 +135,11 @@ def test_rglru_diag_exact_matches_bptt(sparsity):
 
 def test_rglru_streaming_bitwise_equals_scan():
     """The jitted one-step-at-a-time online path replays the whole-sequence
-    scan bit-for-bit — loss and every gradient leaf (f32)."""
+    scan: the loss bit for bit, every gradient element within one ulp (f32).
+    XLA compiles the lone step and the T-step scan as two programs and
+    fuses the trace update and its contraction differently in each, so one
+    rounding of a sum may land one ulp apart (2 of 8 elements of one leaf
+    here); no tighter statement holds across two programs."""
     cfg, params, masks, xs, labels = _rglru_setup(sparsity=0.5)
     T = xs.shape[0]
     learner = make_learner(LearnerSpec(engine="diag_exact", cfg=cfg))
@@ -141,7 +151,8 @@ def test_rglru_streaming_bitwise_equals_scan():
     assert float(carry["loss"]) == float(loss)
     for a, b in zip(jax.tree.leaves(learner.grads(carry)),
                     jax.tree.leaves(grads)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_max_ulp(np.asarray(a), np.asarray(b),
+                                        maxulp=1)
 
 
 def test_rglru_trace_update_flops_scale_linearly_in_n():

@@ -10,7 +10,12 @@ v5e that is described and not attached:
     (n=256, n_in=8, batch 4, omega=0.9), with an f32 and a bf16 carry;
   * `influence.influence_update_pallas` (backend="pallas") at phase B;
   * the jitted `online_update_chunk` of phases A and B, which must hold the
-    fused kernel (`tpu_custom_call`).
+    fused kernel (`tpu_custom_call`);
+  * the head-diagonal trace update of granite-4.0-h-micro's top Mamba-2
+    mixer (`kernels/ssm_trace.py`) at the benchmark cell's shapes (6 rows,
+    8 heads of 64, d_state 128, d_model 2048), and the whole online chunk
+    of that period with its traces donated: one fusion reads and writes
+    the three big traces, in place, and the chunk fits one v5e.
 
 Each compile's `memory_analysis()` is recorded as a test property.  The
 topology is described inside a module fixture, never at import, and the
@@ -31,6 +36,7 @@ from repro.core.cells import stacked_config
 from repro.core.learner import LearnerSpec, make_learner
 from repro.kernels import compact_fused as CF
 from repro.kernels import ops as kops
+from repro.kernels.ssm_trace import ssm_trace_update
 from repro.optim import make_optimizer
 from repro.optim.optimizers import masked
 from repro.runtime.online import online_update_chunk
@@ -152,3 +158,61 @@ def test_online_chunk_compiles_for_v5e(one_chip, kernel_path,
     assert "tpu_custom_call" in compiled.as_text()
     ma = _record(record_property, compiled)
     assert ma.temp_size_in_bytes < 16 * 2 ** 30        # one v5e's HBM
+
+
+# granite-4.0-h-micro's top mixer as the benchmark cell holds it
+SSM = {"B": 6, "Hl": 8, "P": 64, "N": 128, "D": 2048, "K": 4}
+TRACE = "f32[6,8,64,128,2048]"
+
+
+def _trace_fusions(text):
+    """Instructions that produce a big trace: one fusion is one pass."""
+    return [line for line in text.splitlines()
+            if " = " in line and TRACE in line.split(" = ", 1)[1][:400]
+            and "fusion(" in line]
+
+
+def test_ssm_trace_update_compiles_for_v5e(one_chip, record_property):
+    B, Hl, P, N, D, K = (SSM[k] for k in ("B", "Hl", "P", "N", "D", "K"))
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+    s = (B, Hl, P, N)
+    tr = {"x": sd(*s, D), "B": sd(*s, D), "dt": sd(*s, D),
+          "conv_x": sd(*s, K), "conv_x_b": sd(*s), "conv_B": sd(*s, K),
+          "conv_B_b": sd(*s), "A_log": sd(*s), "dt_bias": sd(*s)}
+    inc = dict(tr, x=(sd(*s), sd(B, Hl, P, D)), B=(sd(B, Hl, P), sd(B, N, D)),
+               dt=(sd(*s), sd(B, D)))
+    compiled = jax.jit(ssm_trace_update, donate_argnums=(0,)).lower(
+        tr, sd(B, Hl), inc, sd(*s)).compile()
+    ma = _record(record_property, compiled)
+    big = 3 * B * Hl * P * N * D * 4
+    assert ma.alias_size_in_bytes >= big           # updated in place
+    assert ma.temp_size_in_bytes < big // 100
+    assert len(_trace_fusions(compiled.as_text())) == 1
+
+
+def test_granite_period_chunk_compiles_for_v5e(one_chip, record_property):
+    from repro.cells.mamba2 import GraniteHybridConfig, init_params
+    from repro.obs import MetricPack
+    cfg = GraniteHybridConfig()
+    B = SSM["B"]
+    params, frozen = jax.eval_shape(lambda: init_params(cfg,
+                                                        jax.random.key(0)))
+    learner = make_learner(LearnerSpec(engine="diag_exact", cfg=cfg))
+    opt = make_optimizer("adamw", lr=1e-4, b1=0.9, b2=0.95)
+    carry = jax.eval_shape(lambda p, f: learner.init(
+        p, None, (jnp.zeros((B, 2), jnp.int32), jnp.zeros((B,), jnp.int32)),
+        t_total=K_UPDATE, frozen=f), params, frozen)
+    pack = MetricPack.default()
+    chunk = jax.jit(lambda c, o, xs, ys, upd: online_update_chunk(
+        learner, opt, c, o, xs, ys, upd, pack=pack), donate_argnums=(0, 1))
+    compiled = chunk.lower(
+        _on(one_chip, carry), _on(one_chip, jax.eval_shape(opt.init, params)),
+        jax.ShapeDtypeStruct((K_UPDATE, B, 2), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((K_UPDATE, B), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    ma = _record(record_property, compiled)
+    held = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert ma.alias_size_in_bytes >= ma.argument_size_in_bytes - 2 ** 20
+    assert held < 16e9                              # one v5e's HBM
+    assert len(_trace_fusions(compiled.as_text())) == 1
