@@ -640,7 +640,7 @@ def flat_compact_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     layer's immediate-influence columns inside the stacked parameter axis,
     and `below=(vals_below, idx_below)` adds the cross-layer term
     B^(l) M^(l-1)_t — x_t is then the layer below's activity a^{l-1}_t and
-    the input-Jacobian tiles B-hat are gathered at (new rows, active rows of
+    the input-Jacobian tiles B-hat are looked up at (new rows, active rows of
     the layer below), so the cross term costs K * K_below * P, event-sparse
     on both sides.
 
@@ -653,7 +653,7 @@ def flat_compact_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     this mode (liveness and placement live inside `cl`)."""
     from repro.kernels import compact as CK
     n = layout.n
-    B, K = idx_prev.shape
+    K = idx_prev.shape[1]
     with jax.named_scope("partials"):
         if below is None:
             a_new, hp, Jhat, mbar = cell_partials(cfg, w, a_prev, x_t)
@@ -664,14 +664,12 @@ def flat_compact_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     with jax.named_scope("j_tile_gather"):
         idx_new, count = CK.compact_rows(hp != 0.0, K)
         safe_new = jnp.clip(idx_new, 0, n - 1)
-        live_new = idx_new >= 0
         # rnn J-hat = R^T: lookup tiles straight from R, never building
         # [B, n, n]
         R = w["v"]["R"] if cfg.kind == "rnn" else None
         Jgg = CK.gather_j_tiles(None if R is not None else Jhat,
                                 idx_new, idx_prev, R=R)
-        bidx = jnp.arange(B)[:, None]
-        hp_rows = hp[bidx, safe_new] * live_new
+        hp_rows = CK.take_rows(hp, idx_new)
         if below is not None:
             vals_b, idx_b = below
             if cfg.kind == "rnn":
@@ -724,7 +722,7 @@ def flat_compact_fused_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     from repro.kernels import compact as CK
     from repro.kernels import compact_fused as CF
     n = layout.n
-    B, K = idx_prev.shape
+    K = idx_prev.shape[1]
     if segments is None:
         segments = CF.fused_segments(layout, cl, layer=layer)
     if use_kernel is None:
@@ -739,9 +737,7 @@ def flat_compact_fused_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
     with jax.named_scope("j_tile_gather"):
         idx_new, count = CK.compact_rows(hp != 0.0, K)
         safe_new = jnp.clip(idx_new, 0, n - 1)
-        live_new = idx_new >= 0
-        bidx = jnp.arange(B)[:, None]
-        hp_rows = hp[bidx, safe_new] * live_new
+        hp_rows = CK.take_rows(hp, idx_new)
         count_prev = jnp.sum(idx_prev >= 0, axis=1)
         overflow = jnp.maximum(count - K, 0)
         count_new = jnp.minimum(count, K)
@@ -757,7 +753,7 @@ def flat_compact_fused_step(cfg: EGRUConfig, w: Tree, layout: FlatLayout,
             Bgg = CK.gather_tiles(None if AT is not None else Bhat,
                                   idx_new, idx_b, AT=AT)
     if use_kernel:
-        # TPU grid over the XLA-gathered tiles; M-bar rows built at compact
+        # TPU grid over the tiles looked up in XLA; M-bar rows built at compact
         # width, the cross-layer injection folded into them
         with jax.named_scope("mbar_rows"):
             mbar_rows = flat_mbar_rows_cols(cfg, layout, cl, mbar, safe_new,

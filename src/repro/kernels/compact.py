@@ -77,45 +77,72 @@ def compact_init(B: int, K: int, P: int,
                             jnp.zeros((B,), jnp.int32))
 
 
+# `take_rows` selects rows up to this width and gathers whole rows above
+# it.  A lone J-hat lookup on one TPU v5e (K = n, batch 32; PERF.md,
+# section 6) ran faster with its rows selected at n = 16 (17x when vmapped
+# over 1024 slots) and with them gathered at n = 64 to 256 (1.5-2x); 32
+# lies between.  Wide rows must not be selected: compiled for a v5e at
+# n = 512, XLA fuses the row select into the column select and stores a
+# 4-D select tensor of B n^3 floats, 16 GiB.
+ROW_SELECT_MAX_WIDTH = 32
+
+
+def take_rows(x: jax.Array, idx: jax.Array) -> jax.Array:
+    """x[b, idx[b, k]] for x [Bx, n, ...] (Bx = B, or 1 for a table shared
+    by the batch) and idx [B, K]; a dead slot (-1) reads 0.
+
+    Up to `ROW_SELECT_MAX_WIDTH` rows this is a compare-and-select
+    reduction, sum_i where(i == idx[b, k], x[b, i], 0), which the vector
+    unit runs as one fused elementwise-and-reduce pass; an index gather
+    would run an element (or a short row) at a time.  It is exact: every
+    term but the selected one is an exact zero, so the sum is that entry in
+    any order (only a zero's sign can differ), and an inf or NaN in a row
+    nobody selects never enters a product.  Wider tables are gathered a
+    row at a time."""
+    n = x.shape[1]
+    if n <= ROW_SELECT_MAX_WIDTH:
+        hit = idx[:, :, None] == jnp.arange(n)                  # [B, K, n]
+        hit = hit.reshape(hit.shape + (1,) * (x.ndim - 2))
+        return jnp.where(hit, x[:, None], 0).sum(axis=2)
+    rows = x[jnp.arange(x.shape[0])[:, None], jnp.clip(idx, 0, n - 1)]
+    live = (idx >= 0).reshape(idx.shape + (1,) * (x.ndim - 2))
+    return jnp.where(live, rows, 0)                             # [B, K, ...]
+
+
 def gather_tiles(A: jax.Array | None, idx_row: jax.Array,
                  idx_col: jax.Array, *, AT: jax.Array | None = None):
-    """Gathered [B, K, K_col] tiles of a (possibly rectangular) Jacobian.
-
-    Rows are taken at `idx_row`, columns at `idx_col` (dead column slots —
-    sentinel -1, asserted by `check_idx` — contribute zero columns; dead
-    rows are gated by hp downstream).  Pass the dense per-example ``A``
+    """[B, K, K_col] tiles of a (possibly rectangular) Jacobian: rows at
+    `idx_row`, columns at `idx_col`; a dead slot (sentinel -1, asserted by
+    `check_idx`) in either reads 0.  Pass the dense per-example ``A``
     [B, n_row, n_col] (data-dependent Jacobians, e.g. EGRU J-hat or the
     cross-layer B-hat), or ``AT`` [n_col, n_row] — a weight matrix whose
     TRANSPOSE is the Jacobian (R for the vanilla RNN's J-hat, W for its
     B-hat) — so tiles are looked up directly and [B, n_row, n_col] is
-    never materialized."""
+    never materialized.
+
+    Scope `tile_select`: the rows by `take_rows` (selected or gathered by
+    their width), then the columns by the exact compare-and-select
+    Agg[b, k, l] = sum_j where(j == idx_col[b, l], Ag[b, k, j], 0), which
+    XLA fuses into its reduction, so no [.., K, K_col, n_col] tensor is
+    stored.  On a TPU v5e the per-element column gather this replaces was
+    slower at every width timed (PERF.md, section 6)."""
     if AT is not None:
         n_col, n_row = AT.shape
     else:
         n_row, n_col = A.shape[-2], A.shape[-1]
     check_idx(idx_row, n_row)
     check_idx(idx_col, n_col)
-    B, K = idx_row.shape
-    Kc = idx_col.shape[1]
-    safe_row = jnp.clip(idx_row, 0, n_row - 1)
-    safe_col = jnp.clip(idx_col, 0, n_col - 1)
-    live_col = idx_col >= 0
-    if AT is not None:
-        # A[b, k, j] = AT[j, k]
-        Agg = AT[safe_col[:, None, :], safe_row[:, :, None]]    # [B, K, Kc]
-    else:
-        bidx = jnp.arange(B)[:, None]
-        Ag = A[bidx, safe_row]                                  # [B, K, n_col]
-        Agg = jnp.take_along_axis(
-            Ag, jnp.broadcast_to(safe_col[:, None, :], (B, K, Kc)), axis=2)
-    return Agg * live_col[:, None, :]
+    with jax.named_scope("tile_select"):
+        Ag = take_rows(A if AT is None else AT.T[None], idx_row)  # [B,K,n_col]
+        hit = idx_col[:, None, :, None] == jnp.arange(n_col)
+        return jnp.where(hit, Ag[:, :, None, :], 0).sum(axis=3)
 
 
 def gather_j_tiles(Jhat: jax.Array | None, idx_new: jax.Array,
                    idx_prev: jax.Array, *, R: jax.Array | None = None):
-    """Gathered [B, K, K_prev] tiles of the (square) step Jacobian J-hat:
-    rows at the newly-active unit indices, columns at the previously-active
-    ones.  Thin wrapper over `gather_tiles`."""
+    """[B, K, K_prev] tiles of the (square) step Jacobian J-hat: rows at
+    the newly-active unit indices, columns at the previously-active ones,
+    by compare-and-select.  Thin wrapper over `gather_tiles`."""
     return gather_tiles(Jhat, idx_new, idx_prev, AT=R)
 
 
@@ -148,10 +175,9 @@ def compact_influence_step(hp: jax.Array, Jhat: jax.Array,
     idx_new, count_new = compact_rows(hp != 0.0, K)             # rows of M_t
     bidx = jnp.arange(B)[:, None]
     safe_new = jnp.clip(idx_new, 0, n - 1)
-    live = idx_new >= 0
     Jgg = gather_j_tiles(Jhat, idx_new, Mc.idx)
     Mbar_g = Mbar[bidx, safe_new]                               # [B, K, P]
-    hp_g = hp[bidx, safe_new] * live                            # [B, K]
+    hp_g = take_rows(hp, idx_new)                               # [B, K]
     return compact_update(Jgg, Mc.vals, Mbar_g, hp_g, idx_new, count_new, K)
 
 

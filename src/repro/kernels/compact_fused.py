@@ -26,13 +26,15 @@ changing the carry pytree shape ([B, K, Pc] + [B, K] indices, as before).
 Two lowerings of the SAME block structure:
 
   * `fused_update_pallas` — the TPU kernel (pl.pallas_call): the [B, K, K']
-    J tiles are gathered in XLA (`compact.gather_j_tiles`, 1/Pc of the
-    carry's bytes) and enter as a blocked input, so the kernel body holds
-    only static slices, which Mosaic lowers; the (bk x bl) x (bl x bp)
-    partial products accumulate in f32 on the MXU, M-bar adds and the hp
-    diagonal scale apply before the single output write.  Checked on CPU
-    with interpret=True (tests/test_compact_fused.py) and compiled for a
-    described v5e (tests/test_tpu_compile.py).
+    J tiles are looked up in XLA (`compact.gather_j_tiles`, 1/Pc of the
+    carry's bytes; at the paper's widths an exact compare-and-select
+    reduction, fused, with no gather) and enter as a blocked input, so the
+    kernel body holds only static slices, which Mosaic lowers; the
+    (bk x bl) x (bl x bp) partial products accumulate in f32 on the MXU,
+    M-bar adds and the hp diagonal scale apply before the single output
+    write.  Checked on CPU with interpret=True
+    (tests/test_compact_fused.py) and compiled for a described v5e
+    (tests/test_tpu_compile.py).
   * `fused_update_blocks` — the XLA lowering for hosts without a TPU grid:
     the same per-example blocking, with the data-dependent skip realised as
     a lax.switch over a static capacity ladder (smallest 8-aligned rung
@@ -274,8 +276,8 @@ def fused_update_pallas(Jgg, vals, mbar_rows, hp_rows, count_new, count_prev,
                         interpret: bool | None = None):
     """One fused dual-compact influence update on the TPU grid.
 
-    Jgg [B, K, K] f32 J-hat tiles gathered at (new rows, prev rows) by
-    `compact.gather_j_tiles` (dead prev columns zeroed); vals [B, K, Pc_pad]
+    Jgg [B, K, K] f32 J-hat tiles at (new rows, prev rows) from
+    `compact.gather_j_tiles` (dead slots read 0); vals [B, K, Pc_pad]
     compact carry (f32 or bf16); mbar_rows [B, K, Pc_pad] M-bar gathered at
     the new active rows (hp-ungated); hp_rows [B, K] with dead slots
     zeroed; count_new/count_prev [B] (scalar-prefetched).  Returns the new

@@ -1,6 +1,8 @@
 """Stream fleet: a fleet of 1 is bit-identical to the solo OnlineTrainer,
 slots join/leave mid-flight without perturbing their neighbours' bits, and
 evict -> resume through the session store round-trips exactly."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -323,3 +325,26 @@ def test_resume_into_a_pending_slot_keeps_its_loaded_state(tmp_path):
     for f in (ref, fleet):
         f.step_window()
     _tree_equal(fleet.slot_state("a"), ref.slot_state("a"))
+
+
+_SLICES = re.compile(r"slice_sizes=\{([0-9,]*)\}")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def test_fleet_chunk_looks_up_tiles_without_element_gathers():
+    """The vmapped chunk of 4 slots of the n=16 EGRU (compact, col_compact)
+    looks its J-hat tiles up by compare-and-select: no `gather` under the
+    `j_tile_gather` scope takes one element per index (all slice sizes 1),
+    the op that took 87% of the fleet's window on a TPU v5e."""
+    cfg, masks, learner, opt, params = _setup("compact", True, n=16)
+    fleet = StreamFleet(FleetConfig(slots=4, update_every=2), learner, opt,
+                        params, masks, example=_stream()(0))
+    args = [jnp.asarray(a) for a in fleet._gather(2)]
+    hlo = fleet._chunk.lower(fleet.carry, fleet.opt_state,
+                             *args).compile().as_text()
+    scoped = [line for line in hlo.splitlines()
+              if "j_tile_gather" in (_OP_NAME.search(line) or [""])[0]]
+    assert any("j_tile_gather/tile_select" in line for line in scoped)
+    elementwise = [line for line in scoped if " gather(" in line
+                   and set(_SLICES.search(line).group(1).split(",")) == {"1"}]
+    assert not elementwise, elementwise

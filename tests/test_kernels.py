@@ -87,6 +87,111 @@ def test_compact_overflow_reported():
     assert int(overflow[0]) == n - K
 
 
+# --- tile lookup: compare-and-select vs the per-element gather --------------
+
+def _gather_tiles_ref(A, idx_row, idx_col):
+    """The lookup as an index gather: rows by a row gather, columns by a
+    `take_along_axis` with one index per tile element; dead slots read 0."""
+    B, K = idx_row.shape
+    n_row, n_col = A.shape[-2:]
+    Ag = A[jnp.arange(B)[:, None], jnp.clip(idx_row, 0, n_row - 1)]
+    Agg = jnp.take_along_axis(
+        Ag, jnp.broadcast_to(jnp.clip(idx_col, 0, n_col - 1)[:, None, :],
+                             (B, K, idx_col.shape[1])), axis=2)
+    live = (idx_row >= 0)[:, :, None] & (idx_col >= 0)[:, None, :]
+    return jnp.where(live, Agg, 0.0)
+
+
+def _lookup_case(kind, poison, col_cap, seed):
+    """(A or None, AT or None, idx_row, idx_col, the dense A the indices
+    read).  Row capacity 16 > n_row = 12 pads with dead slots, as a column
+    capacity above n_col does; unit 0 and the last unit are inactive
+    everywhere, so a clipped dead slot would read them; `poison` puts inf
+    or NaN there."""
+    n_row, n_col = {"square": (12, 12), "rect": (12, 20), "AT": (12, 20)}[kind]
+    key = jax.random.key(seed)
+    ks = jax.random.split(key, 3)
+
+    def idx(k, n, K):
+        on = jax.random.bernoulli(k, 0.6, (3, n)).at[:, 0].set(False)
+        return compact.compact_rows(on.at[:, n - 1].set(False), K)[0]
+
+    idx_row, idx_col = idx(ks[0], n_row, 16), idx(ks[1], n_col, col_cap)
+    A = jax.random.normal(ks[2], (3, n_row, n_col))
+    if poison is not None:
+        bad = jnp.inf if poison == "inf" else jnp.nan
+        A = A.at[:, 0].set(bad).at[:, :, 0].set(bad)
+        A = A.at[:, n_row - 1].set(-bad).at[:, :, n_col - 1].set(bad)
+    if kind == "AT":
+        AT = A[0].T
+        return None, AT, idx_row, idx_col, jnp.broadcast_to(AT.T, A.shape)
+    return A, None, idx_row, idx_col, A
+
+
+@pytest.mark.parametrize("vmapped", [False, True])
+@pytest.mark.parametrize("poison", [None, "inf", "nan"])
+@pytest.mark.parametrize("rows", ["select", "gather"])
+@pytest.mark.parametrize("col_cap", [8, 24])
+@pytest.mark.parametrize("kind", ["square", "rect", "AT"])
+def test_gather_tiles_bitwise_equals_index_gather(monkeypatch, kind, col_cap,
+                                                  rows, poison, vmapped):
+    """The lookup (12 rows, selected or gathered by the row width rule;
+    12 or 20 columns by compare-and-select, 8 or 24 column slots) equals
+    the per-element gather bit for bit (zeros of either sign alike), dead
+    slots and K > n padding read 0, an inf or NaN nobody selects never
+    leaks into a tile, and a gather is compiled only for gathered rows."""
+    monkeypatch.setattr(compact, "ROW_SELECT_MAX_WIDTH",
+                        12 if rows == "select" else 11)
+    cases = [_lookup_case(kind, poison, col_cap, s)
+             for s in range(3 if vmapped else 1)]
+    if vmapped:
+        stack = lambda i: None if cases[0][i] is None else jnp.stack(
+            [c[i] for c in cases])
+        args, dense = tuple(stack(i) for i in range(4)), stack(4)
+    else:
+        args, dense = cases[0][:4], cases[0][4]
+
+    def look(A, AT, ir, ic):
+        return compact.gather_tiles(A, ir, ic, AT=AT)
+
+    ref = _gather_tiles_ref
+    if vmapped:
+        look = jax.vmap(look, in_axes=tuple(None if a is None else 0
+                                            for a in args))
+        ref = jax.vmap(ref)
+    got, want = look(*args), ref(dense, *args[2:])
+    assert got.shape == want.shape == args[2].shape + args[3].shape[-1:]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.isfinite(np.asarray(got)).all()
+    lowered = jax.jit(look).lower(*args)
+    assert (" gather(" in lowered.compiler_ir("hlo").as_hlo_text()) \
+        == (rows == "gather")
+    assert "tile_select" in lowered.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("width", [16, 15])
+@pytest.mark.parametrize("trailing", [(), (3,)])
+def test_take_rows_dead_slots_read_zero(monkeypatch, trailing, width):
+    """Rows of a [B, n] or [B, n, 3] table (n = 16), selected (width 16)
+    or gathered (15), equal the index gather's bits; a dead slot reads 0,
+    not row 0's inf; only gathered rows compile to a gather."""
+    monkeypatch.setattr(compact, "ROW_SELECT_MAX_WIDTH", width)
+    x = jax.random.uniform(jax.random.key(3), (4, 16) + trailing)
+    x = x.at[:, 0].set(jnp.inf)
+    on = jax.random.bernoulli(jax.random.key(4), 0.5, (4, 16)).at[:, 0].set(
+        False)
+    idx, _ = compact.compact_rows(on, 16)
+    got = compact.take_rows(x, idx)
+    live = (idx >= 0).reshape(idx.shape + (1,) * len(trailing))
+    want = jnp.where(live, x[jnp.arange(4)[:, None], jnp.clip(idx, 0, 15)],
+                     0.0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert np.isfinite(np.asarray(got)).all()
+    hlo = jax.jit(lambda x, i: compact.take_rows(x, i)).lower(
+        x, idx).compiler_ir("hlo").as_hlo_text()
+    assert (" gather(" in hlo) == (width < 16)
+
+
 # --- chunked flash attention vs naive oracle --------------------------------
 
 @pytest.mark.parametrize("S,H,KV,causal,window",

@@ -34,6 +34,7 @@ from repro.configs import egru_spiral
 from repro.core import cells, stacked_rtrl as ST
 from repro.core.cells import stacked_config
 from repro.core.learner import LearnerSpec, make_learner
+from repro.kernels import compact
 from repro.kernels import compact_fused as CF
 from repro.kernels import ops as kops
 from repro.kernels.ssm_trace import ssm_trace_update
@@ -140,6 +141,23 @@ def test_influence_kernel_compiles_for_v5e(one_chip, record_property):
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _record(record_property, compiled)
+
+
+@pytest.mark.parametrize("n", [16, 64, 512])
+def test_tile_lookup_compiles_for_v5e_without_its_select_tensor(
+        one_chip, record_property, n):
+    """The J-hat tile lookup (K = n, batch 32) on both sides of the row
+    width rule stores no 4-D select tensor (B n^3 floats, 16 GiB at
+    n = 512, where selecting the rows as well made XLA store one); its
+    temporaries stay within one [B, K, n] row block."""
+    B = 32
+    sd = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    idx = sd((B, n), jnp.int32)
+    compiled = jax.jit(compact.gather_j_tiles).lower(
+        sd((B, n, n)), idx, idx).compile()
+    ma = _record(record_property, compiled)
+    assert ma.temp_size_in_bytes <= B * n * n * 4
 
 
 @pytest.mark.parametrize("phase", ["A", "B"])
